@@ -47,12 +47,6 @@ class ModelConfig:
     num_frontend_tokens: int = 0  # stubbed modality-frontend token count
     # --- training defaults ---
     train_accum: int = 4   # microbatch grad-accumulation (fits residuals in HBM)
-    # --- kernels ---
-    # Route attention through the Pallas kernels (kernels/ops.py dispatch:
-    # compiled on TPU, interpret mode on CPU when ops.set_backend
-    # ("interpret") is active) instead of the jnp fallback. Identical
-    # semantics either way; CI exercises the interpret path.
-    use_kernel: bool = False
     # --- numerics / misc ---
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5

@@ -33,14 +33,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.distributed.compat import PallasCompilerParams as _CompilerParams
 
 NEG_INF = -1e30
+
+# packed_chunk_attention's q block: callers align packed row starts to it
+PACKED_BLOCK_Q = 8
 
 
 def _chunk_kernel(off_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, bq: int, bk: int,
                   nk: int, window: int):
+    b = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -50,8 +53,8 @@ def _chunk_kernel(off_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_off = off_ref[0]                      # absolute position of chunk row 0
-    q_len = qlen_ref[0]                     # valid rows in this chunk
+    q_off = off_ref[b]                      # absolute position of chunk row 0
+    q_len = qlen_ref[b]                     # valid rows in this chunk
     q_first = q_off + qi * bq               # absolute position of block row 0
     k_first = ki * bk
     # per-row block skip: the block's last VALID row position bounds the kv
@@ -125,26 +128,33 @@ def chunk_attention(q, k_cache, v_cache, q_offsets, q_lens=None, *,
         _chunk_kernel, scale=1.0 / math.sqrt(hd), bq=bq, bk=bk, nk=nk,
         window=window)
 
-    out = pl.pallas_call(
-        kernel,
+    # per-row scalars ride in SMEM by scalar prefetch (whole [B] arrays):
+    # Pallas TPU refuses a rank-1 (1,) SMEM block that is neither the whole
+    # array nor a multiple of 128
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, qi, ki: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h, qi, ki: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, qi, ki: (b, h // g, ki, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, qi, ki: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, bq, hd),
+                         lambda b, h, qi, ki, of, ql: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda b, h, qi, ki, of, ql: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda b, h, qi, ki, of, ql: (b, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, C_pad, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd),
+                               lambda b, h, qi, ki, of, ql: (b, h, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, C_pad, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_offsets.astype(jnp.int32), q_lens.astype(jnp.int32), qh, kh, vh)
@@ -208,7 +218,8 @@ def _packed_chunk_kernel(brow_ref, starts_ref, offs_ref, qlens_ref,
 @functools.partial(
     jax.jit, static_argnames=("window", "block_q", "block_k", "interpret"))
 def packed_chunk_attention(q, k_cache, v_cache, row_starts, q_offsets,
-                           q_lens, *, window: int = 0, block_q: int = 8,
+                           q_lens, *, window: int = 0,
+                           block_q: int = PACKED_BLOCK_Q,
                            block_k: int = 256, interpret: bool = False):
     """Token-packed ragged chunk attention: q [Np, H, hd] concatenates every
     row's chunk tokens on ONE axis (row b occupies packed positions
@@ -273,7 +284,7 @@ def packed_chunk_attention(q, k_cache, v_cache, row_starts, q_offsets,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, Np_pad, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(brow, starts, q_offsets.astype(jnp.int32), q_lens.astype(jnp.int32),

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.distributed.compat import PallasCompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -31,6 +30,7 @@ NEG_INF = -1e30
 def _flash_kernel(off_ref, klen_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                   acc_ref, *, scale: float, bq: int, bk: int, nk: int,
                   window: int):
+    b = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -40,8 +40,8 @@ def _flash_kernel(off_ref, klen_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_offset = off_ref[0]                   # this sequence's chunk offset
-    kv_len = klen_ref[0]                    # this sequence's valid kv length
+    q_offset = off_ref[b]                   # this sequence's chunk offset
+    kv_len = klen_ref[b]                    # this sequence's valid kv length
     q_first = qi * bq + q_offset            # absolute position of q block row 0
     q_last = q_first + bq - 1
     k_first = ki * bk
@@ -117,26 +117,32 @@ def flash_attention(q, k, v, *, q_offset: int = 0, window: int = 0,
         _flash_kernel, scale=1.0 / math.sqrt(hd), bq=bq, bk=bk, nk=nk,
         window=window)
 
-    out = pl.pallas_call(
-        kernel,
+    # per-sequence scalars ride in SMEM by scalar prefetch (whole [B]
+    # arrays): Pallas TPU refuses a rank-1 (1,) SMEM block unless B == 1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, qi, ki: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h, qi, ki: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, qi, ki: (b, h // g, ki, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, qi, ki: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, bq, hd),
+                         lambda b, h, qi, ki, of, kl: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda b, h, qi, ki, of, kl: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda b, h, qi, ki, of, kl: (b, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq_pad, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd),
+                               lambda b, h, qi, ki, of, kl: (b, h, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq_pad, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_offsets.astype(jnp.int32), kv_lens.astype(jnp.int32), qh, kh, vh)

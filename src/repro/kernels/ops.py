@@ -1,10 +1,11 @@
 """jit'd dispatch wrappers over the Pallas kernels.
 
 Backend selection:
-  "tpu"       -- compiled Pallas (real hardware target)
+  "tpu"       -- compiled Pallas (the default on a TPU)
   "interpret" -- Pallas interpret mode (CPU validation; used in tests)
-  "jnp"       -- pure-jnp reference path (default on CPU, used by dry-run)
-Set globally with set_backend() or per-call with backend=...
+  "jnp"       -- pure-jnp reference path (the default elsewhere)
+Set globally with set_backend() or per-call with backend=... The models'
+attention (models/layers.py) follows the global choice.
 """
 from __future__ import annotations
 
@@ -76,6 +77,13 @@ def packed_chunk_attention(q, k_cache, v_cache, row_starts, q_offsets,
     return _da.packed_chunk_attention(q, k_cache, v_cache, row_starts,
                                       q_offsets, q_lens, window=window,
                                       interpret=(b == "interpret"), **kw)
+
+
+def packed_row_align() -> int:
+    """Alignment of packed row starts that ``packed_chunk_attention`` needs
+    on the backend in use: the Pallas kernel's q block (no block may
+    straddle two rows), or 1 for the jnp path, which packs rows densely."""
+    return 1 if default_backend() == "jnp" else _da.PACKED_BLOCK_Q
 
 
 def decode_attention(q, k_cache, v_cache, seq_lens, *, window=0, backend=None, **kw):

@@ -13,8 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.distributed.compat import PallasCompilerParams as _CompilerParams
-
 
 def _rglru_kernel(log_a_ref, bx_ref, h0_ref, h_ref, hlast_ref, carry_ref, *,
                   bt: int, nt: int):
@@ -75,7 +73,7 @@ def rglru(log_a, bx, h0, *, block_b: int = 8, block_w: int = 512,
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bb, bw), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, bx, h0)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import all_archs, get_config, get_shapes
-from repro.distributed.compat import set_mesh
 from repro.distributed.sharding import logical_to_spec, rules_for, spec_tree
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
                                make_production_mesh, num_chips)
@@ -197,7 +196,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     fn, args, meta = built
     chips = num_chips(mesh)
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -232,11 +231,11 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         pfn, pargs, _ = b
         _layers.SCAN_UNROLL = True   # trip-count-correct cost_analysis
         try:
-            with set_mesh(m2):
+            with jax.set_mesh(m2):
                 pl = pfn.lower(*pargs)
         finally:
             _layers.SCAN_UNROLL = False
-        with set_mesh(m2):
+        with jax.set_mesh(m2):
             pc = pl.compile()
         cost = pc.cost_analysis()
         coll = collective_bytes(pc.as_text())

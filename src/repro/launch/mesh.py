@@ -8,8 +8,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.distributed.compat import make_mesh
-
 # TPU v5e per-chip constants (roofline denominators)
 PEAK_FLOPS_BF16 = 197e12        # FLOP/s
 HBM_BW = 819e9                  # B/s
@@ -19,12 +17,14 @@ ICI_BW = 50e9                   # B/s per link
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Single-host mesh for tests/examples (1x1 on CPU)."""
-    return make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def num_chips(mesh) -> int:

@@ -69,8 +69,16 @@ def run_workload(*, arch="tiny", scheduler="rr", quantum=16, num_cores=1,
     if metrics_server is not None:
         metrics_server.shutdown()
     sr = sum(1 for r in results if r.get("success")) / max(len(results), 1)
+    # an agent fails when its run raised (no result), its task check failed
+    # (success False; None marks a framework without the API), or any of
+    # the syscalls the kernel settled did not finish as done
+    failed_agents = (agents - len(results)) + sum(
+        1 for r in results if r.get("success") is False)
+    failed_syscalls = len(kernel.scheduler.completed) - m["completed"]
     out = {"agents": agents, "seconds": round(dt, 2),
-           "success_rate": sr, "completed_syscalls": m["completed"],
+           "success_rate": sr, "failed_agents": failed_agents,
+           "failed_syscalls": failed_syscalls,
+           "completed_syscalls": m["completed"],
            "avg_wait_s": round(m["avg_wait"], 4),
            "p90_wait_s": round(m["p90_wait"], 4),
            "throughput_syscalls_per_s": round(m["completed"] / dt, 2)}
@@ -98,8 +106,10 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None,
                     help="write one final Prometheus text scrape here")
     args = ap.parse_args(argv)
-    run_workload(**{k.replace("-", "_"): v for k, v in vars(args).items()})
+    out = run_workload(**{k.replace("-", "_"): v
+                          for k, v in vars(args).items()})
+    return 1 if out["failed_agents"] or out["failed_syscalls"] else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -13,6 +13,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
+
 Params = Dict[str, Any]
 
 # Cost-probe switch (launch/dryrun.py): XLA's cost_analysis counts while-loop
@@ -85,18 +87,18 @@ def _broadcast_kv(k, n_heads: int):
 # attention -- chunked causal (train / prefill) and decode-over-cache
 # ---------------------------------------------------------------------------
 
-def causal_attention(q, k, v, *, q_offset=0, window: int = 0, q_block: int = 512,
-                     use_kernel: bool = False):
+def causal_attention(q, k, v, *, q_offset=0, window: int = 0, q_block: int = 512):
     """Causal (optionally sliding-window) attention.
 
     q: [B, Sq, H, hd]; k, v: [B, Skv, K, hd] (K divides H; GQA broadcast).
     q_offset: absolute position of q[0] relative to k[0] (prefill continuation).
     Memory-efficient: scans over Q blocks so scores never materialize at
-    [Sq, Skv] full size. The Pallas flash kernel (kernels/flash_attention.py)
-    is the TPU hot path; this is the jnp fallback with identical semantics.
+    [Sq, Skv] full size. The backend in use (``kops.default_backend()``:
+    the platform, or what ``kops.set_backend`` chose) picks the Pallas flash
+    kernel (kernels/flash_attention.py) or this jnp path, with identical
+    semantics.
     """
-    if use_kernel:
-        from repro.kernels import ops as kops
+    if kops.default_backend() != "jnp":
         return kops.flash_attention(q, k, v, q_offset=q_offset, window=window)
     B, Sq, H, hd = q.shape
     Skv = k.shape[1]
@@ -158,7 +160,7 @@ def _attn_block(q, k, v, q_pos, kv_pos, scale, window):
 
 
 def chunk_attention(q, k_cache, v_cache, q_offsets, *, q_lens=None,
-                    window: int = 0, use_kernel: bool = False):
+                    window: int = 0):
     """Prefix+chunk causal attention (chunked prefill): query row i of
     sequence b sits at absolute position ``q_offsets[b] + i`` and attends to
     cache positions ``0 .. q_offsets[b] + i`` (optionally sliding-window).
@@ -171,12 +173,11 @@ def chunk_attention(q, k_cache, v_cache, q_offsets, *, q_lens=None,
     rows (q_len == 1 -- a degenerate chunk at the current position) and
     inactive rows (q_len == 0): the kernel skips dead q/kv blocks per row.
     Rows produce garbage at query positions past q_len (mask their K/V
-    writes instead). The Pallas kernel
-    (kernels/decode_attention.chunk_attention) is the TPU hot path; this is
-    the jnp fallback with identical semantics for the valid rows.
+    writes instead). Off the jnp backend this is the Pallas kernel
+    (kernels/decode_attention.chunk_attention); the jnp path below has
+    identical semantics for the valid rows.
     """
-    if use_kernel:
-        from repro.kernels import ops as kops
+    if kops.default_backend() != "jnp":
         return kops.chunk_attention(q, k_cache, v_cache, q_offsets, q_lens,
                                     window=window)
     B, C, H, hd = q.shape
@@ -214,20 +215,20 @@ def packed_row_index(row_starts, q_lens, n_packed: int):
 
 
 def packed_chunk_attention(q, k_cache, v_cache, row_starts, q_offsets,
-                           q_lens, *, window: int = 0,
-                           use_kernel: bool = False):
+                           q_lens, *, window: int = 0):
     """Token-packed ragged variant of ``chunk_attention``: q [Np, H, hd]
     concatenates every row's chunk tokens on ONE packed axis (row b occupies
     ``row_starts[b] .. row_starts[b] + q_lens[b] - 1``); caches stay
     [B, S, K, hd] with the chunk's K/V already written. FLOPs scale with the
     real tokens in the dispatch -- a decode row costs 1 packed slot, a
     7-token tail chunk costs 7 -- instead of rows x chunk bucket. The jnp
-    fallback trades that FLOPs win for a gathered [Np, S, K, hd] read of the
-    caches (fine at CPU research scale; the Pallas kernel DMAs per-block
-    instead). Packed positions past a row's q_len produce zeros. Returns
-    [Np, H, hd]."""
-    if use_kernel:
-        from repro.kernels import ops as kops
+    path trades that FLOPs win for a gathered [Np, S, K, hd] read of the
+    caches (fine at CPU research scale, 1 GiB per layer at yi-6b widths;
+    the Pallas kernel, taken off the jnp backend, DMAs per block instead).
+    Packed positions past a row's q_len produce zeros. Returns
+    [Np, H, hd]; off the jnp backend ``row_starts`` must be aligned to
+    ``kops.packed_row_align()``."""
+    if kops.default_backend() != "jnp":
         return kops.packed_chunk_attention(q, k_cache, v_cache, row_starts,
                                            q_offsets, q_lens, window=window)
     Np, H, hd = q.shape
@@ -253,16 +254,16 @@ def packed_chunk_attention(q, k_cache, v_cache, row_starts, q_offsets,
     return jnp.where(valid[:, None, None], out, 0)
 
 
-def decode_attention(q, k_cache, v_cache, seq_lens, *, window: int = 0,
-                     use_kernel: bool = False):
+def decode_attention(q, k_cache, v_cache, seq_lens, *, window: int = 0):
     """One-token attention against a contiguous KV cache.
 
     q: [B, H, hd]; caches: [B, S, K, hd]; seq_lens: [B] (valid prefix length,
-    including the token written for this step). Returns [B, H, hd].
+    including the token written for this step). Returns [B, H, hd]. Off the
+    jnp backend this is the Pallas kernel.
     """
-    if use_kernel:
-        from repro.kernels import ops as kops
-        return kops.decode_attention(q, k_cache, v_cache, seq_lens, window=window)
+    if kops.default_backend() != "jnp":
+        return kops.decode_attention(q, k_cache, v_cache, seq_lens,
+                                     window=window)
     B, S, K, hd = k_cache.shape
     H = q.shape[1]
     g = H // K

@@ -142,7 +142,7 @@ class MoETransformer(DenseTransformer):
             x, aux = carry
             h = L.rms_norm(x, blk["ln1"], cfg.norm_eps)
             q, kk, vv = L.attn_qkv(blk["attn"], h, cfg, positions)
-            o = L.causal_attention(q, kk, vv, use_kernel=cfg.use_kernel)
+            o = L.causal_attention(q, kk, vv)
             x = x + L.attn_out(blk["attn"], o)
             x, a = self._mlp_part(blk, x)
             return (x, aux + a), None
@@ -176,7 +176,7 @@ class MoETransformer(DenseTransformer):
             blk, kc, vc = xs
             h = L.rms_norm(x, blk["ln1"], cfg.norm_eps)
             q, kk, vv = L.attn_qkv(blk["attn"], h, cfg, positions)
-            o = L.causal_attention(q, kk, vv, use_kernel=cfg.use_kernel)
+            o = L.causal_attention(q, kk, vv)
             x = x + L.attn_out(blk["attn"], o)
             x, _ = self._mlp_part(blk, x, infer=True)
             kc = jax.lax.dynamic_update_slice_in_dim(kc, kk, 0, axis=1)
@@ -206,8 +206,7 @@ class MoETransformer(DenseTransformer):
             q, kk, vv = L.attn_qkv(blk["attn"], h, cfg, positions)
             kc = L.cache_write_token(kc, kk[:, 0], seq_lens)
             vc = L.cache_write_token(vc, vv[:, 0], seq_lens)
-            o = L.decode_attention(q[:, 0], kc, vc, seq_lens + 1,
-                                   use_kernel=cfg.use_kernel)
+            o = L.decode_attention(q[:, 0], kc, vc, seq_lens + 1)
             x = x + L.attn_out(blk["attn"], o[:, None])
             x, _ = self._mlp_part(blk, x, infer=True)
             return x, (kc, vc)

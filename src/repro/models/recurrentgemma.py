@@ -179,8 +179,7 @@ class RecurrentGemma:
         cfg = self.cfg
         h = L.rms_norm(x, blk["ln1"], cfg.norm_eps)
         q, k, v = L.attn_qkv(blk["attn"], h, cfg, positions)
-        o = L.causal_attention(q, k, v, window=cfg.window,
-                               use_kernel=cfg.use_kernel)
+        o = L.causal_attention(q, k, v, window=cfg.window)
         x = x + L.attn_out(blk["attn"], o)
         h = L.rms_norm(x, blk["ln2"], cfg.norm_eps)
         x = x + L.mlp_apply(blk["mlp"], h, cfg.activation)

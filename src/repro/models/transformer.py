@@ -30,9 +30,12 @@ def _is_logical(x):
 
 
 def _stack_init(rng, n: int, init_fn):
-    """vmap an init over layer rngs -> params stacked on a leading "layers"
+    """Map an init over layer rngs -> params stacked on a leading "layers"
     axis. init_fn(rng) -> (params, logical); logical (static strings) is
-    harvested via a side channel since vmap outputs must be arrays."""
+    harvested via a side channel since mapped outputs must be arrays. The
+    map is a loop, one layer at a time, so a draw's float32 temporaries
+    span one layer's leaf, never the whole stack (at yi-6b widths the
+    stacked MLP leaf would be 5.8 GB in float32)."""
     ks = jax.random.split(rng, n)
     side = {}
 
@@ -41,7 +44,7 @@ def _stack_init(rng, n: int, init_fn):
         side["logical"] = l
         return p
 
-    params = jax.vmap(params_only)(ks)
+    params = jax.lax.map(params_only, ks)
     logical = jax.tree.map(lambda l: ("layers",) + l, side["logical"],
                            is_leaf=_is_logical)
     return params, logical
@@ -123,8 +126,7 @@ class DenseTransformer:
         cfg = self.cfg
         h = L.rms_norm(x, blk["ln1"], cfg.norm_eps)
         q, k, v = L.attn_qkv(blk["attn"], h, cfg, positions)
-        o = L.causal_attention(q, k, v, q_offset=q_offset,
-                                use_kernel=cfg.use_kernel)
+        o = L.causal_attention(q, k, v, q_offset=q_offset)
         x = x + L.attn_out(blk["attn"], o)
         h = L.rms_norm(x, blk["ln2"], cfg.norm_eps)
         x = x + L.mlp_apply(blk["mlp"], h, cfg.activation)
@@ -247,8 +249,7 @@ class DenseTransformer:
                     sblk, kcl, vcl = sub
                     h = L.rms_norm(x2, sblk["ln1"], cfg.norm_eps)
                     q, k, v = L.attn_qkv(sblk["attn"], h, cfg, positions)
-                    o = L.causal_attention(q, k, v,
-                                           use_kernel=cfg.use_kernel)
+                    o = L.causal_attention(q, k, v)
                     x2 = x2 + L.attn_out(sblk["attn"], o)
                     h = L.rms_norm(x2, sblk["ln2"], cfg.norm_eps)
                     x2 = x2 + L.mlp_apply(sblk["mlp"], h, cfg.activation)
@@ -333,8 +334,7 @@ class DenseTransformer:
             vw = vc[:, :kv_width] if narrow else vc
             kw = L.cache_write_chunk(kw, k, q_offset, lengths)
             vw = L.cache_write_chunk(vw, v, q_offset, lengths)
-            o = L.chunk_attention(q, kw, vw, q_offset, q_lens=lengths,
-                                  use_kernel=cfg.use_kernel)
+            o = L.chunk_attention(q, kw, vw, q_offset, q_lens=lengths)
             if narrow:
                 kc = jax.lax.dynamic_update_slice_in_dim(kc, kw, 0, axis=1)
                 vc = jax.lax.dynamic_update_slice_in_dim(vc, vw, 0, axis=1)
@@ -448,7 +448,7 @@ class DenseTransformer:
             kw = L.cache_write_packed(kw, k[0], row, pos, valid)
             vw = L.cache_write_packed(vw, v[0], row, pos, valid)
             o = L.packed_chunk_attention(q[0], kw, vw, row_starts, q_offset,
-                                         lengths, use_kernel=cfg.use_kernel)
+                                         lengths)
             if narrow:
                 kc = jax.lax.dynamic_update_slice_in_dim(kc, kw, 0, axis=1)
                 vc = jax.lax.dynamic_update_slice_in_dim(vc, vw, 0, axis=1)
@@ -553,8 +553,7 @@ class DenseTransformer:
             q, k, v = L.attn_qkv(blk["attn"], h, cfg, positions)
             kc = L.cache_write_token(kc, k[:, 0], seq_lens)
             vc = L.cache_write_token(vc, v[:, 0], seq_lens)
-            o = L.decode_attention(q[:, 0], kc, vc, seq_lens + 1,
-                                   use_kernel=cfg.use_kernel)
+            o = L.decode_attention(q[:, 0], kc, vc, seq_lens + 1)
             x = x + L.attn_out(blk["attn"], o[:, None])
             h = L.rms_norm(x, blk["ln2"], cfg.norm_eps)
             x = x + L.mlp_apply(blk["mlp"], h, cfg.activation)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops as kops
 from repro.models import build_model
 from repro.obs.profiler import (KIND_DECODE, KIND_IMAGE, KIND_NAMES,
                                 KIND_PACKED, KIND_PADDED, KIND_SERIAL,
@@ -189,7 +190,12 @@ class _EngineJits:
             is_leaf=lambda x: isinstance(x, tuple) and all(
                 isinstance(e, (str, type(None))) for e in x))
 
-        @jax.jit
+        # every program that returns the engine's next cache takes the old
+        # one by donation: at full width the cache is 1 GiB, and without
+        # donation each tick holds the old and the new one at once
+        donate = functools.partial(jax.jit, donate_argnames=("cache",))
+
+        @donate
         def decode(params, tokens, cache, active_mask):
             new, logits = model.decode_step(params, tokens, cache)
             # inactive slots keep their ENTIRE cache row bit-for-bit: decoding
@@ -223,7 +229,7 @@ class _EngineJits:
                 return jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=ax)
             return jax.tree.map(get, cache, baxes)
 
-        @functools.partial(jax.jit, static_argnames=("kv",))
+        @functools.partial(donate, static_argnames=("kv",))
         def prefill_chunk(params, tokens, cache, q_offset, lengths, kv):
             """Consume one token chunk for every queued sequence in a single
             dispatch, writing K/V (or recurrent state) straight into the
@@ -235,7 +241,7 @@ class _EngineJits:
                                        q_offset=q_offset, lengths=lengths,
                                        kv_width=kv)
 
-        @functools.partial(jax.jit, static_argnames=("kv", "chunk"))
+        @functools.partial(donate, static_argnames=("kv", "chunk"))
         def prefill_packed(params, tokens, cache, row_starts, q_offset,
                            lengths, kv, chunk):
             """Token-packed ragged chunk dispatch: ``tokens`` [Np] carries
@@ -251,7 +257,7 @@ class _EngineJits:
                                         q_offset=q_offset, lengths=lengths,
                                         chunk=chunk, kv_width=kv)
 
-        @functools.partial(jax.jit, static_argnames=("kv", "upto"))
+        @functools.partial(donate, static_argnames=("kv", "upto"))
         def prefill_chunk_spec(params, tokens, cache, q_offset, lengths, kv,
                                upto):
             """Chunk dispatch that ALSO returns per-position logits for the
@@ -264,8 +270,7 @@ class _EngineJits:
                                        q_offset=q_offset, lengths=lengths,
                                        kv_width=kv, logits_upto=upto)
 
-        @functools.partial(jax.jit,
-                           static_argnames=("kv", "chunk", "upto"))
+        @functools.partial(donate, static_argnames=("kv", "chunk", "upto"))
         def prefill_packed_spec(params, tokens, cache, row_starts, q_offset,
                                 lengths, kv, chunk, upto):
             """Packed-axis twin of ``prefill_chunk_spec``: per-position
@@ -276,7 +281,7 @@ class _EngineJits:
                                         chunk=chunk, kv_width=kv,
                                         logits_upto=upto)
 
-        @functools.partial(jax.jit, static_argnames=("kv", "chunk"))
+        @functools.partial(donate, static_argnames=("kv", "chunk"))
         def prefill_packed_img(params, tokens, cache, row_starts, q_offset,
                                lengths, image_embeds, image_mask, kv, chunk):
             """Packed ragged dispatch carrying stacked frontend embeddings:
@@ -291,7 +296,7 @@ class _EngineJits:
                                         image_embeds=image_embeds,
                                         image_mask=image_mask)
 
-        @functools.partial(jax.jit, static_argnames=("kv",))
+        @functools.partial(donate, static_argnames=("kv",))
         def mixed_decode(params, tokens, cache, active_mask, kv):
             """Pure-decode tick of the unified serve path: every active slot
             is a length-1 chunk row at its own ``seq_lens`` position,
@@ -305,7 +310,7 @@ class _EngineJits:
                 params, toks, cache, q_offset=cache["seq_lens"],
                 lengths=active_mask.astype(jnp.int32), kv_width=kv)
 
-        @functools.partial(jax.jit, static_argnames=("kv",))
+        @functools.partial(donate, static_argnames=("kv",))
         def prefill_chunk_img(params, tokens, cache, q_offset, lengths,
                               image_embeds, image_mask, kv):
             """Chunk dispatch with stacked frontend embeddings: rows flagged
@@ -351,7 +356,7 @@ class _EngineJits:
 
         self.decode = decode
         self.prefill_packed = prefill_packed
-        self.insert = jax.jit(insert)
+        self.insert = donate(insert)
         self.extract = jax.jit(extract)
         self.prefill_chunk = prefill_chunk
         self.prefill_chunk_img = prefill_chunk_img
@@ -360,15 +365,15 @@ class _EngineJits:
         self.prefill_packed_img = prefill_packed_img
         self.mixed_decode = mixed_decode
         self.gather_rows = jax.jit(gather_rows)
-        self.scatter_rows = jax.jit(scatter_rows)
+        self.scatter_rows = donate(scatter_rows)
         self.reset_rows = jax.jit(reset_rows)
 
-        @jax.jit
+        @donate
         def set_seq_len(cache, slot, value):
             return dict(cache, seq_lens=cache["seq_lens"].at[slot].set(value))
         self.set_len = set_seq_len
 
-        @jax.jit
+        @donate
         def set_seq_lens(cache, slots, values):
             """Batched seq_lens write -- the WHOLE speculative rollback:
             truncating a slot's seq_len to its committed position makes the
@@ -419,7 +424,9 @@ _JIT_CACHE_LOCK = threading.Lock()
 
 
 def _jits_for(cfg, temperature: float) -> _EngineJits:
-    key = (repr(cfg), float(temperature))
+    # the attention backend is read while a program traces, so programs
+    # traced for one backend are never handed to an engine on another
+    key = (repr(cfg), float(temperature), kops.default_backend())
     with _JIT_CACHE_LOCK:
         js = _JIT_CACHE.get(key)
         if js is None:
@@ -487,7 +494,10 @@ class ServingEngine:
         self.max_len = max_len
         self.temperature = temperature
         if params is None:
-            params, _ = self.model.init_params(jax.random.key(rng_seed))
+            # one program draws, scales and casts: the float32 draw never
+            # exists as a whole leaf next to the bf16 params on the device
+            params = jax.jit(lambda k: self.model.init_params(k)[0])(
+                jax.random.key(rng_seed))
         self.params = params
         self.cache, self.cache_logical = self.model.init_cache(max_slots, max_len)
         self._batch_axes = self._jits.batch_axes
@@ -1331,7 +1341,7 @@ class ServingEngine:
             # prefill chunks, drafts ride free only if they don't push the
             # packed token axis into a LARGER bucket -- prefill throughput
             # (the paid-for debt) outranks speculative upside
-            al = 8 if self.cfg.use_kernel else 1
+            al = kops.packed_row_align()
 
             def _ptot(with_drafts: bool) -> int:
                 tot = 0
@@ -1393,12 +1403,12 @@ class ServingEngine:
         # packed bucket smaller than the [kb, C] rectangle, issue them
         # on one flat axis -- a decode row costs 1 token, a 7-token
         # tail chunk costs 7, not C. Row segments are aligned to the
-        # Pallas block_q (8) when the kernel path is on so block rows
+        # packed kernel's q block on a Pallas backend so block rows
         # never straddle two sequences; the gap slots carry zero pad
         # tokens that the per-row length mask kills. Image rows join the
         # packed axis too (their TEXT tokens pack; the frontend embeddings
         # stay a per-row dense tensor -- padded-within-packed).
-        align = 8 if self.cfg.use_kernel else 1
+        align = kops.packed_row_align()
         row_starts = np.zeros((kb,), np.int32)
         cur = 0
         for r in range(kb):

@@ -11,8 +11,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import compat
-
 
 def _quantize(g) -> Tuple[jnp.ndarray, jnp.ndarray]:
     g32 = g.astype(jnp.float32)
@@ -26,7 +24,7 @@ def compressed_psum(grads, axis_names):
     the scales, dequantize. Mean over the DP group is folded into scales."""
     n = 1
     for ax in axis_names:
-        n = n * compat.axis_size(ax)
+        n = n * jax.lax.axis_size(ax)
 
     def one(g):
         q, scale = _quantize(g)
@@ -40,7 +38,7 @@ def compressed_psum(grads, axis_names):
 def plain_psum_mean(grads, axis_names):
     n = 1
     for ax in axis_names:
-        n = n * compat.axis_size(ax)
+        n = n * jax.lax.axis_size(ax)
     return jax.tree.map(
         lambda g: (jax.lax.psum(g.astype(jnp.float32), axis_names) / n
                    ).astype(g.dtype), grads)

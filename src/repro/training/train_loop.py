@@ -14,8 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.distributed.compat import make_mesh, set_mesh
 from repro.distributed.sharding import logical_to_spec, rules_for, spec_tree
+from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.models.api import abstract_init
 from repro.training.checkpoint import CheckpointManager
@@ -93,8 +93,7 @@ class Trainer:
         self.cfg = cfg
         self.tc = tc
         self.log = log
-        self.mesh = mesh if mesh is not None else make_mesh(
-            (1, 1), ("data", "model"))
+        self.mesh = mesh if mesh is not None else make_local_mesh()
         self.model = build_model(cfg)
         from repro.training.optimizer import warmup_cosine
         opt_kw = {"lr": warmup_cosine(tc.lr, tc.warmup, tc.steps)}
@@ -112,7 +111,7 @@ class Trainer:
         self.batch_spec = NamedSharding(
             self.mesh, logical_to_spec(("batch", "seq"), self.rules))
 
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             init_fn = jax.jit(
                 lambda k: self.model.init_params(k)[0],
                 out_shardings=self.param_sharding)
@@ -181,7 +180,7 @@ class Trainer:
                                      on_retry=lambda a, e: self.log(
                                          f"step retry {a}: {e}"))
         t0 = time.time()
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             for step in range(self.start_step, steps):
                 batch = next(it)
                 self.monitor.arm(step)

@@ -215,8 +215,10 @@ def test_packed_chunk_attention_matches_ref(window):
 
 def test_packed_equals_padded_chunk_rows():
     """The packed layout is a re-indexing, not a different computation:
-    each row's packed slice must equal the corresponding padded
-    chunk_attention row over the same cache."""
+    each row's packed slice must match the corresponding padded
+    chunk_attention row over the same cache. The two fp32 references
+    contract in different einsum orders, so XLA may round differently:
+    they agree to fp32 rounding (1e-6), not bit for bit."""
     ks = jax.random.split(jax.random.key(23), 3)
     B, C, S, H, K, hd = 3, 16, 96, 4, 2, 16
     qlens = jnp.array([C, 1, 7], jnp.int32)
@@ -232,9 +234,9 @@ def test_packed_equals_padded_chunk_rows():
                                             qlens)
     padded = ref.chunk_attention_ref(qpad, kc, vc, offs, qlens)
     for b in range(B):
-        np.testing.assert_array_equal(
+        np.testing.assert_allclose(
             np.asarray(packed)[starts[b]:starts[b] + qlens[b]],
-            np.asarray(padded)[b, :qlens[b]])
+            np.asarray(padded)[b, :qlens[b]], atol=1e-6, rtol=1e-6)
 
 
 def test_chunk_attention_ignores_stale_cache_tail():
@@ -354,12 +356,13 @@ def test_wkv6_chunked_jnp_path_matches_sequential():
 
 
 # ---------------------------------------------------------------------------
-# use_kernel config wiring (model -> kernels/ops dispatch)
+# backend wiring (model -> kernels/ops dispatch)
 # ---------------------------------------------------------------------------
 def test_use_kernel_config_routes_serving_through_pallas_interpret():
-    """`ModelConfig.use_kernel=True` must route the serving engine's chunked
-    prefill + decode through the Pallas kernels (interpret mode on CPU) and
-    produce the same tokens as the jnp fallback path."""
+    """The attention backend -- the platform's, or what ``ops.set_backend``
+    chose -- must route the serving engine's chunked prefill, packed
+    dispatch and decode through the Pallas kernels (interpret mode on CPU)
+    and produce the same tokens as the jnp path."""
     from repro.configs import get_config
     from repro.serving.engine import ServingEngine
 
@@ -367,7 +370,7 @@ def test_use_kernel_config_routes_serving_through_pallas_interpret():
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, 500, n).astype(np.int32) for n in (8, 33, 70)]
 
-    def run(cfg):
+    def run():
         eng = ServingEngine(cfg, max_slots=4, max_len=128, rng_seed=0)
         slots = eng.add_sequences([dict(prompt=p, max_new=6)
                                    for p in prompts], eager=False)
@@ -375,13 +378,15 @@ def test_use_kernel_config_routes_serving_through_pallas_interpret():
             eng.prefill_step()
         while any(not eng.is_done(s) for s in slots):
             eng.step()
-        return [eng.result(s) for s in slots]
+        return [eng.result(s) for s in slots], eng.stats
 
-    expect = run(cfg)
-    assert cfg.use_kernel is False
+    assert ops.default_backend() == "jnp"
+    expect, _ = run()
     ops.set_backend("interpret")
     try:
-        out = run(cfg.replace(use_kernel=True))
+        assert ops.packed_row_align() == 8
+        out, stats = run()
     finally:
         ops.set_backend(None)
     assert out == expect
+    assert stats["packed_dispatches"] > 0

@@ -62,9 +62,11 @@ def test_rr_preempts_long_generations():
 
 def test_rr_interleaves_fairly():
     """With RR, a short job submitted after a long one should not wait for
-    the long job to finish (contrast with FIFO)."""
+    the long job to finish (contrast with FIFO). The long job must outlast
+    the pause before the short one arrives: warm, 48 tiny-model tokens
+    take 30-90 ms on a CPU, against the 50 ms pause."""
     with make_kernel("rr", quantum=4) as k:
-        long_sc = _llm("long", max_new=48)
+        long_sc = _llm("long", max_new=200)
         k.submit(long_sc)
         time.sleep(0.05)
         short_sc = _llm("short", max_new=4)

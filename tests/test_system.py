@@ -104,3 +104,17 @@ def test_memory_via_sdk(kernel):
     hits = api.search_memories(kernel, "m1", "what orbits the earth",
                                k=1)["search_results"]
     assert hits and "moon" in hits[0]["content"]
+
+
+@pytest.mark.parametrize("broken", [False, True], ids=["ok", "task_fails"])
+def test_serve_launcher_exit_code(monkeypatch, broken):
+    """`python -m repro.launch.serve` exits 0 only when every agent and
+    every syscall succeeded."""
+    from repro.agents import frameworks
+    from repro.launch import serve
+
+    if broken:
+        monkeypatch.setattr(frameworks, "_check", lambda task, result: False)
+    rc = serve.main(["--arch", "tiny", "--agents", "2", "--max-new", "2",
+                     "--max-len", "128", "--scheduler", "batched"])
+    assert rc == (1 if broken else 0)

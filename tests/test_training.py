@@ -11,7 +11,6 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.configs import get_config
-from repro.distributed import compat
 from repro.models import build_model
 from repro.training import (AdamW, CheckpointManager, StragglerMonitor,
                             SyntheticLM, TrainConfig, Trainer,
@@ -125,20 +124,22 @@ class TestTrainerEndToEnd:
 
 class TestCompression:
     def test_int8_psum_roundtrip(self):
-        mesh = compat.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         g = {"w": jnp.linspace(-1, 1, 64).reshape(8, 8)}
 
         def f(grads):
             return compressed_psum(grads, ("data",))
-        out = compat.shard_map(f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec(),),
+        out = jax.shard_map(f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec(),),
                             out_specs=jax.sharding.PartitionSpec())(g)
         err = float(jnp.max(jnp.abs(out["w"] - g["w"])))
         assert err < 1.0 / 127 + 1e-6   # one quantization step
 
     def test_plain_psum_mean_identity_on_single_device(self):
-        mesh = compat.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         g = {"w": jnp.arange(4.0)}
-        out = compat.shard_map(lambda x: plain_psum_mean(x, ("data",)), mesh=mesh,
+        out = jax.shard_map(lambda x: plain_psum_mean(x, ("data",)), mesh=mesh,
                             in_specs=(jax.sharding.PartitionSpec(),),
                             out_specs=jax.sharding.PartitionSpec())(g)
         np.testing.assert_allclose(out["w"], g["w"], rtol=1e-6)

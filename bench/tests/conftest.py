@@ -1,0 +1,11 @@
+"""CPU self-tests of the benchmark: ``python -m pytest bench/tests``."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (os.path.join(ROOT, "src"), ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("REPRO_XLA_CACHE", "0")

@@ -7,12 +7,12 @@ from __future__ import annotations
 
 import itertools
 import threading
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from repro.core.syscall import LLMSyscall, SyscallCancelled
+from repro.obs import span
 from repro.serving.engine import ServingEngine
 
 
@@ -26,7 +26,6 @@ class LLMCore:
         self.ctx = context_manager
         self.core_id = core_id
         self._lock = threading.Lock()   # exclusive-mode serialization
-        self.busy_time = 0.0
         self.executed = 0
         self.migrations_out = 0          # contexts handed to another core
         self.migrations_in = 0           # contexts restored from another core
@@ -97,14 +96,16 @@ class LLMCore:
         prompt_tokens = getattr(sc, "_prefill_tokens", None)
         if prompt_tokens is None:
             prompt_tokens = len(self.engine.slots[slot].prompt)
-        try:
-            # grown resubmissions extend, not re-prefill
-            self.engine.harvest_prefix(slot)
-        except Exception:  # noqa: BLE001 -- caching is best-effort: a
-            # storage-tier fault during the persist must not fail (or
-            # retry) a generation that already FINISHED
-            self.harvest_errors += 1
-        self.engine.free(slot)
+        with span("aios.core.finish", tracer=self.engine.tracer, pid=sc.pid,
+                  tokens=len(tokens)):
+            try:
+                # grown resubmissions extend, not re-prefill
+                self.engine.harvest_prefix(slot)
+            except Exception:  # noqa: BLE001 -- caching is best-effort: a
+                # storage-tier fault during the persist must not fail (or
+                # retry) a generation that already FINISHED
+                self.harvest_errors += 1
+            self.engine.free(slot)
         return {"tokens": tokens, "finished": True,
                 "usage": {"new_tokens": len(tokens),
                           "prompt_tokens": int(prompt_tokens)}}
@@ -115,9 +116,11 @@ class LLMCore:
         migration hand-off path: the snapshot is kept in host RAM (never
         spilled to disk) until the receiving core restores it, so a
         cross-core migration costs one host round-trip, not two plus disk."""
-        snap = self.engine.snapshot(slot, kind=self.ctx.mode)
         ctx_id = f"ctx-{sc.pid}"
-        self.ctx.save(ctx_id, snap, pinned=pinned)
+        with span("aios.core.suspend", tracer=self.engine.tracer,
+                  pid=sc.pid):
+            snap = self.engine.snapshot(slot, kind=self.ctx.mode)
+            self.ctx.save(ctx_id, snap, pinned=pinned)
         if pinned:
             self.migrations_out += 1
             sc._migrated_from = self.core_id
@@ -127,7 +130,6 @@ class LLMCore:
     def execute_llm_syscall(self, sc: LLMSyscall,
                             quantum: Optional[int] = None
                             ) -> Tuple[bool, Any]:
-        t0 = time.monotonic()
         with self._lock:
             slot = self.admit(sc)
             try:
@@ -137,7 +139,6 @@ class LLMCore:
                         raise SyscallCancelled(f"pid={sc.pid}")
                     if quantum is not None and steps >= quantum:
                         ctx_id = self._suspend(sc, slot)
-                        self.busy_time += time.monotonic() - t0
                         return False, ctx_id
                     self.engine.step()
                     steps += 1
@@ -151,9 +152,7 @@ class LLMCore:
                     self.engine.free(slot)
                 except Exception:  # noqa: BLE001
                     pass
-                self.busy_time += time.monotonic() - t0
                 raise
-        self.busy_time += time.monotonic() - t0
         self.executed += 1
         return True, resp
 

@@ -14,6 +14,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from repro.core.syscall import Syscall, SyscallCancelled
+from repro.obs import span
 
 
 class _PriorityQueue:
@@ -770,7 +771,10 @@ class BatchedScheduler(BaseScheduler):
                     continue
                 sc.mark_running()
                 try:
-                    slot = core.admit(sc, eager=False)
+                    with span("aios.sched.admit", tracer=self.tracer,
+                              pid=sc.pid, prompt_tokens=len(
+                                  sc.request_data.get("prompt", ()))):
+                        slot = core.admit(sc, eager=False)
                 except RuntimeError:
                     # lost the capacity race (slots/pages went to another
                     # admission); hand back for re-dispatch

@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.memory.pagetable import KVPage, PageTable
 from repro.obs.trace import PID_MEMORY as _PID_MEMORY
+from repro.obs.trace import span
 from repro.serving.paging import PageAllocator
 
 # int8 per-channel scales are stored bf16 (fp32 exponent range, 2 bytes --
@@ -435,24 +436,29 @@ class KVPageStore:
     def _enforce_host_budget(self, pinned: set) -> None:
         if self._host_used <= self.host_budget_bytes:
             return
-        # demote LRU host pages to disk; refcount-0 persisted pages first
-        # (their blob already exists), then referenced ones (prefix entries /
-        # suspended contexts re-hydrate on next use)
-        victims = sorted(self.table.by_lru("host"),
-                         key=lambda p: (p.refs > 0, p.last_use))
-        for page in victims:
-            if self._host_used <= self.host_budget_bytes:
-                return
-            if page.pid in pinned:
-                continue
-            if page.refs == 0:
-                if page.durable:
-                    self._retire(page)
-                else:
-                    self._free(page)
-                continue
-            if not self._demote_to_disk(page):
-                continue   # no storage tier attached: page stays resident
+        with span("aios.pagestore.demote", tracer=self.tracer) as sp:
+            # demote LRU host pages to disk; refcount-0 persisted pages
+            # first (their blob already exists), then referenced ones
+            # (prefix entries / suspended contexts re-hydrate on next use)
+            victims = sorted(self.table.by_lru("host"),
+                             key=lambda p: (p.refs > 0, p.last_use))
+            pages = to_disk = 0
+            for page in victims:
+                if self._host_used <= self.host_budget_bytes:
+                    break
+                if page.pid in pinned:
+                    continue
+                if page.refs == 0:
+                    if page.durable:
+                        self._retire(page)
+                    else:
+                        self._free(page)
+                    continue
+                if self._demote_to_disk(page):
+                    pages += 1
+                    to_disk += page.nbytes
+                # else no storage tier attached: page stays resident
+            sp.set(pages=pages, bytes_to_disk=to_disk)
 
     # -- put / leaves / release ----------------------------------------------------
     def put(self, layout_key: str, leaves: Sequence[Any], *, seq_len: int,
@@ -464,48 +470,57 @@ class KVPageStore:
         snapshots)."""
         lay = self._layouts[layout_key]
         ps = self.page_size
-        host = [np.asarray(x) for x in leaves]
         # no pageable leaves -> no pages (an empty-slice page per range
         # would alias to one degenerate pid); everything rides residual
         npages = -(-max(0, int(seq_len)) // ps) if lay.paged_idx else 0
-        page_ids: List[str] = []
-        with self.table.lock:
-            for p in range(npages):
-                t0 = p * ps
-                width = min(ps, seq_len - t0)
-                slices = []
-                for i in lay.paged_idx:
-                    ax = lay.time_axes[i]
-                    leaf = host[i]
-                    sl = [slice(None)] * leaf.ndim
-                    sl[ax] = slice(t0, t0 + width)
-                    slices.append(np.ascontiguousarray(leaf[tuple(sl)]))
-                # identity (and hence dedup) is ALWAYS over the original fp
-                # bytes -- quantization changes a page's representation,
-                # never its id
-                pid = self._digest(layout_key, slices)
-                page = self.table.get(pid)
-                if page is not None:
-                    page.last_use = self._tick()
-                    self.stats["dedup_hits"] += 1
-                    self.stats["dedup_saved_bytes"] += page.nbytes
-                else:
-                    page = self._make_page(
-                        pid, slices, width, origin, device,
-                        taxes=[lay.time_axes[i] for i in lay.paged_idx])
-                self.stats["put_bytes"] += page.nbytes   # logical (pre-dedup)
-                self.table.incref(pid)
-                page_ids.append(pid)
-            residual = [host[i] for i in lay.residual_idx]
-            nbytes = sum(self.table.get(pid).nbytes for pid in set(page_ids))
-            nbytes += sum(a.nbytes for a in residual)
-            self._residual_bytes += sum(a.nbytes for a in residual)
-            # fully assembled: even the new pages are fair demotion victims
-            # under the watermark (a read re-hydrates them from disk)
-            self._enforce_host_budget(set())
-            self.stats["put_handles"] += 1
-            return PagedKV(self, layout_key, page_ids, residual, seq_len,
-                           nbytes)
+        with span("aios.pagestore.put", tracer=self.tracer,
+                  pages=npages) as sp:
+            host = [np.asarray(x) for x in leaves]
+            page_ids: List[str] = []
+            new = 0
+            with self.table.lock:
+                for p in range(npages):
+                    t0 = p * ps
+                    width = min(ps, seq_len - t0)
+                    slices = []
+                    for i in lay.paged_idx:
+                        ax = lay.time_axes[i]
+                        leaf = host[i]
+                        sl = [slice(None)] * leaf.ndim
+                        sl[ax] = slice(t0, t0 + width)
+                        slices.append(np.ascontiguousarray(leaf[tuple(sl)]))
+                    # identity (and hence dedup) is ALWAYS over the original
+                    # fp bytes -- quantization changes a page's
+                    # representation, never its id
+                    pid = self._digest(layout_key, slices)
+                    page = self.table.get(pid)
+                    if page is not None:
+                        page.last_use = self._tick()
+                        self.stats["dedup_hits"] += 1
+                        self.stats["dedup_saved_bytes"] += page.nbytes
+                    else:
+                        new += 1
+                        page = self._make_page(
+                            pid, slices, width, origin, device,
+                            taxes=[lay.time_axes[i] for i in lay.paged_idx])
+                    # logical (pre-dedup) bytes
+                    self.stats["put_bytes"] += page.nbytes
+                    self.table.incref(pid)
+                    page_ids.append(pid)
+                residual = [host[i] for i in lay.residual_idx]
+                nbytes = sum(self.table.get(pid).nbytes
+                             for pid in set(page_ids))
+                nbytes += sum(a.nbytes for a in residual)
+                self._residual_bytes += sum(a.nbytes for a in residual)
+                # fully assembled: even the new pages are fair demotion
+                # victims under the watermark (a read re-hydrates them from
+                # disk)
+                self._enforce_host_budget(set())
+                self.stats["put_handles"] += 1
+                handle = PagedKV(self, layout_key, page_ids, residual, seq_len,
+                                 nbytes)
+            sp.set(new_pages=new, dedup_hits=npages - new, bytes=nbytes)
+        return handle
 
     def leaves(self, handle: PagedKV) -> List[np.ndarray]:
         """Rebuild the full flat leaf list of a handle: paged leaves are
@@ -570,11 +585,17 @@ class KVPageStore:
         with self.table.lock:
             if handle._released:
                 return
-            handle._released = True
-            self.stats["released_handles"] += 1
-            self._residual_bytes -= sum(a.nbytes for a in handle.residual)
-            for pid in handle.page_ids:
-                self._drop_ref(pid)
+            freed0 = self.stats["freed_pages"]
+            retired0 = self.stats["retired_pages"]
+            with span("aios.pagestore.release", tracer=self.tracer) as sp:
+                handle._released = True
+                self.stats["released_handles"] += 1
+                self._residual_bytes -= sum(a.nbytes
+                                            for a in handle.residual)
+                for pid in handle.page_ids:
+                    self._drop_ref(pid)
+                sp.set(freed=self.stats["freed_pages"] - freed0,
+                       retired=self.stats["retired_pages"] - retired0)
 
     def pin_pages(self, handle: PagedKV) -> None:
         """Short-lived extra reference covering the window between a cache

@@ -19,7 +19,6 @@ registry exports.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Dict
 
 import numpy as np
@@ -36,9 +35,8 @@ KIND_NAMES = ("decode", "packed", "padded", "image", "serial", "spec")
 
 
 class TickProfiler:
-    def __init__(self, cap: int = 4096, clock=time.perf_counter):
+    def __init__(self, cap: int = 4096):
         self.cap = int(cap)
-        self.clock = clock
         self.n = 0                      # ticks recorded (lifetime)
         c = self.cap
         self._kind = np.zeros(c, np.int8)

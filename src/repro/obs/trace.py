@@ -19,9 +19,18 @@ instants for point events, timestamps in microseconds from the tracer's
 start. Events live in a bounded ring (oldest dropped first, counted) so a
 long-running kernel cannot grow without bound.
 
+The serving path's layer boundaries are ``span``s: each is always a
+``jax.profiler.TraceAnnotation`` (in a profiler session it lands on the
+host plane of the same trace as the device's ops), and with a tracer also
+an "X" event here. Both stamp the profiler's host clock (``now_ns``, the
+realtime clock); the export records the tracer's origin on it, so a
+Chrome trace and a profiler trace of the same seconds line up after one
+fixed shift.
+
 Cost model: a span is one dict append under a lock -- microseconds, paid
 per lifecycle transition or per engine tick, never per token. A kernel
-without a tracer pays a single ``is None`` attribute check at each site.
+without a tracer pays a single ``is None`` attribute check at each site;
+a ``span`` outside a profiler session costs one native call more.
 """
 from __future__ import annotations
 
@@ -31,25 +40,32 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # Chrome-trace "process" lanes: one per subsystem so Perfetto groups the
-# timeline as syscalls / engine ticks / memory-tier traffic.
+# timeline as syscalls / engine ticks / memory-tier traffic / the host
+# threads' serving-path spans.
 PID_SYSCALLS = 1
 PID_ENGINE = 2
 PID_MEMORY = 3
+PID_SPANS = 4
 
 _PROCESS_NAMES = {PID_SYSCALLS: "syscalls", PID_ENGINE: "engines",
-                  PID_MEMORY: "kv-pages"}
+                  PID_MEMORY: "kv-pages", PID_SPANS: "host spans"}
+
+# The clock the profiler stamps host events with (CLOCK_REALTIME, in ns):
+# jax.profiler.ProfileData reports them relative to the session's
+# ``profile_start_time`` on the same clock.
+now_ns = time.time_ns
 
 
 class Tracer:
     """Bounded ring of Chrome-trace events; thread-safe; µs timestamps
-    relative to construction (``time.monotonic`` based)."""
+    from ``origin_ns`` on the profiler's host clock (``now_ns``)."""
 
-    def __init__(self, *, cap: int = 262144, enabled: bool = True,
-                 clock=time.monotonic):
+    def __init__(self, *, cap: int = 262144, enabled: bool = True):
         self.enabled = enabled
-        self._clock = clock
-        self._t0 = clock()
+        self.origin_ns = now_ns()
         self._buf: deque = deque(maxlen=max(1, int(cap)))
         self._lock = threading.Lock()
         self._named = set()          # (pid, tid) lanes already labelled
@@ -59,7 +75,11 @@ class Tracer:
 
     # -- clock / low-level emit --------------------------------------------------
     def now_us(self) -> float:
-        return (self._clock() - self._t0) * 1e6
+        return self.us(now_ns())
+
+    def us(self, t_ns: int) -> float:
+        """A ``now_ns`` stamp as this tracer's µs timestamp."""
+        return (t_ns - self.origin_ns) * 1e-3
 
     def _emit(self, ev: Dict[str, Any]) -> None:
         if not self.enabled:
@@ -121,7 +141,13 @@ class Tracer:
             return list(self._buf)
 
     def chrome_trace(self) -> Dict[str, Any]:
-        return {"traceEvents": self.events(), "displayTimeUnit": "ms"}
+        """The events, with the origin of their timestamps: an event at
+        ``ts`` µs sits at ``origin_ns + 1000 * ts`` on the realtime clock,
+        which is a profiler trace's ``profile_start_time`` plus its
+        event's start."""
+        return {"traceEvents": self.events(), "displayTimeUnit": "ms",
+                "otherData": {"clock": "realtime",
+                              "origin_ns": self.origin_ns}}
 
     def export(self, path: str) -> int:
         """Write Perfetto-loadable JSON; returns the event count."""
@@ -136,6 +162,53 @@ class Tracer:
         return {"events": n, "dropped": self.dropped,
                 "roots_opened": self.roots_opened,
                 "roots_closed": self.roots_closed}
+
+
+class span:
+    """One serving-path boundary, as a context manager::
+
+        with span("aios.core.finish", tracer=tr, pid=sc.pid) as sp:
+            ...
+            sp.set(tokens=n)
+
+    Always a ``jax.profiler.TraceAnnotation`` named ``name`` with the args
+    as its metadata: JAX records it only inside a profiler session, on the
+    host plane of the device's trace. With ``tracer`` the same interval is
+    also an "X" event on the "host spans" lane of the calling thread, so
+    spans nest there as they do in the profiler. ``pid`` is
+    the syscall the span serves and rides in both as an arg; ``args`` are
+    counts, and ``set`` adds those known only at the end."""
+
+    __slots__ = ("name", "args", "_tracer", "_ann", "_t0")
+
+    def __init__(self, name: str, *, tracer: Optional[Tracer] = None,
+                 pid: Optional[int] = None, **args: Any):
+        if pid is not None:
+            args["pid"] = pid
+        self.name = name
+        self.args = args
+        self._tracer = tracer
+
+    def __enter__(self) -> "span":
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._t0 = now_ns()
+        return self
+
+    def set(self, **args: Any) -> None:
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        tr = self._tracer
+        if tr is not None:
+            t1 = now_ns()
+            tid = threading.get_native_id()
+            tr.name_track(PID_SPANS, tid, threading.current_thread().name)
+            tr.complete(self.name, PID_SPANS, tid, tr.us(self._t0),
+                        (t1 - self._t0) * 1e-3, dict(self.args))
+        self._ann.__exit__(*exc)
 
 
 class SyscallTrace:

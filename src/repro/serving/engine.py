@@ -39,7 +39,7 @@ from repro.models import build_model
 from repro.obs.profiler import (KIND_DECODE, KIND_IMAGE, KIND_NAMES,
                                 KIND_PACKED, KIND_PADDED, KIND_SERIAL,
                                 KIND_SPEC)
-from repro.obs.trace import PID_ENGINE
+from repro.obs.trace import PID_ENGINE, now_ns, span
 from repro.serving import sampler as smp
 from repro.serving.paging import PageAllocator
 
@@ -490,6 +490,8 @@ class ServingEngine:
                                                # committed last tick (the
                                                # scheduler's token-accurate
                                                # quantum accounting)
+        self._tick_span = None                 # open serve_step span
+        self._tick_t0_ns = 0                   # tick start, tracer clock
         self.max_slots = max_slots
         self.max_len = max_len
         self.temperature = temperature
@@ -1047,10 +1049,9 @@ class ServingEngine:
                 self.params, jnp.asarray(buf), self._cache_b1, lengths)
         self.stats["model_dispatches"] += 1
         if cacheable and self.prefix_cache is not None:
-            self._cache_prefix(tokens, cache1, logits[0])
+            self._cache_prefix(tokens, logits[0], cache1=cache1)
         self._activate_slot(slot, cache1, logits[0])
-        if _t0:
-            self._obs_tick(KIND_SERIAL, _t0, _t0, 1, 1, Spad, Spad, P, Spad)
+        self._obs_tick(KIND_SERIAL, _t0, _t0, 1, 1, Spad, Spad, P, Spad)
 
     def _activate_slot(self, slot: int, cache1, logits_vec):
         """Insert a ready batch-1 cache into `slot` and sample its pending
@@ -1080,32 +1081,46 @@ class ServingEngine:
         self.counters = self.counters.at[slot].set(s.counter)
 
     # -- prefix cache (restore, then chunk-prefill the suffix) --------------------
-    def _cache_prefix(self, tokens: np.ndarray, cache1, logits_vec):
-        """Store a batch-1 cache tree + last-position logits under `tokens`.
+    def _cache_prefix(self, tokens: np.ndarray, logits_vec, *,
+                      slot: Optional[int] = None, cache1=None):
+        """Store `slot`'s cache row (or a ready batch-1 ``cache1``) + its
+        last-position logits under `tokens`.
         Legacy path: leaves stay on device as a private blob. Page-store
-        path: the state is paged into the shared table at the device tier
-        (charged against the store's PageAllocator budget), so prefixes that
-        agree share pages with each other and with the contexts extending
-        them, and the entry is write-through persisted for cross-process
-        re-hydration."""
+        path: the state is read to the host and paged into the shared table
+        at the device tier (charged against the store's PageAllocator
+        budget), so prefixes that agree share pages with each other and
+        with the contexts extending them, and the entry is write-through
+        persisted for cross-process re-hydration."""
         tokens = np.asarray(tokens, np.int32)
-        if self.page_store is not None:
-            handle = self.page_store.put(
-                self._layout_key, jax.tree.leaves(cache1),
-                seq_len=len(tokens), origin=self.engine_id, device=True)
-            snap = ContextSnapshot(
-                kind="prefix", prompt=tokens.copy(), generated=[],
-                seq_len=len(tokens), pages=handle,
-                logits=np.asarray(logits_vec), origin=self.engine_id)
-            if not self.prefix_cache.insert(snap):
-                handle.release()
-            return
-        snap = ContextSnapshot(
-            kind="prefix", prompt=tokens.copy(),
-            generated=[], seq_len=len(tokens),
-            state=list(jax.tree.leaves(cache1)), logits=logits_vec,
-            origin=self.engine_id)
-        self.prefix_cache.insert(snap)
+        pid = self.slots[slot].seq_id if slot is not None else None
+        with span("aios.prefix.store", tracer=self.tracer, pid=pid,
+                  seq_tokens=len(tokens)) as sp:
+            with span("aios.engine.extract", tracer=self.tracer) as ex:
+                if slot is not None:
+                    cache1 = self._extract_jit(self.cache, slot)
+                leaves = jax.tree.leaves(cache1)
+                if self.page_store is not None:
+                    leaves = [np.asarray(x) for x in leaves]
+                ex.set(bytes=sum(x.nbytes for x in leaves))
+            if self.page_store is not None:
+                handle = self.page_store.put(
+                    self._layout_key, leaves, seq_len=len(tokens),
+                    origin=self.engine_id, device=True)
+                snap = ContextSnapshot(
+                    kind="prefix", prompt=tokens.copy(), generated=[],
+                    seq_len=len(tokens), pages=handle,
+                    logits=np.asarray(logits_vec), origin=self.engine_id)
+                accepted = self.prefix_cache.insert(snap)
+                if not accepted:
+                    handle.release()
+            else:
+                snap = ContextSnapshot(
+                    kind="prefix", prompt=tokens.copy(),
+                    generated=[], seq_len=len(tokens),
+                    state=list(leaves), logits=logits_vec,
+                    origin=self.engine_id)
+                accepted = self.prefix_cache.insert(snap)
+            sp.set(accepted=int(accepted))
 
     def harvest_prefix(self, slot: int):
         """Cache a finishing sequence's full context (prompt + generation) so
@@ -1117,33 +1132,43 @@ class ServingEngine:
         if not s.active or not s.generated:
             return
         tokens = np.concatenate([s.prompt, np.asarray(s.generated, np.int32)])
-        piece = self._extract_jit(self.cache, slot)
-        self._cache_prefix(tokens, piece, jnp.asarray(self._last_logits[slot]))
+        self._cache_prefix(tokens, jnp.asarray(self._last_logits[slot]),
+                           slot=slot)
 
     # -- observability ----------------------------------------------------------------
     def _obs_t0(self) -> float:
         """Tick start stamp when any observer is attached, else 0.0 (the
-        single-branch fast path for untraced engines)."""
+        single-branch fast path for untraced engines). With a tracer the
+        same instant is also stamped on its clock (``now_ns``), where the
+        tick's span starts."""
         if self.profiler is None and self.tracer is None:
             return 0.0
+        if self.tracer is not None:
+            self._tick_t0_ns = now_ns()
         return time.perf_counter()
 
     def _obs_tick(self, kind: int, t0: float, t_build: float, rows: int,
                   kb: int, chunk: int, kv: int, tokens: int,
                   padded: int) -> None:
-        """Close one tick sample: ring-buffer scalar stores for the profiler
-        plus (when tracing) one engine-lane span. Wall time is host-observed;
-        the engine syncs on the NEXT tick's pending-token read, so
-        steady-state tick walls are honest without adding a device sync."""
+        """Close one tick: label the open ``aios.engine.serve_step`` span,
+        then, when observed, ring-buffer scalar stores for the profiler
+        plus (when tracing) one engine-lane span from the tick's own start.
+        Wall time is host-observed; the engine syncs on the NEXT tick's
+        pending-token read, so steady-state tick walls are honest without
+        adding a device sync."""
+        if self._tick_span is not None:
+            self._tick_span.set(kind=KIND_NAMES[kind], rows=rows,
+                                tokens=int(tokens))
+        if not t0:
+            return
         t1 = time.perf_counter()
         if self.profiler is not None:
             self.profiler.record(kind, t1 - t0, t_build - t0, rows, kb,
                                  chunk, kv, int(tokens), int(padded))
         tr = self.tracer
         if tr is not None:
-            dur = (t1 - t0) * 1e6
             tr.complete("tick", PID_ENGINE, self.engine_id,
-                        tr.now_us() - dur, dur,
+                        tr.us(self._tick_t0_ns), (t1 - t0) * 1e6,
                         {"kind": KIND_NAMES[kind], "rows": rows, "kb": kb,
                          "chunk": chunk, "kv": kv, "tokens": int(tokens)})
 
@@ -1182,7 +1207,8 @@ class ServingEngine:
                                                   self.cache, mask)
         self._last_logits = logits
         nxt = self._sample_all_jit(logits, self.seq_keys, self.counters)
-        tok_host = np.asarray(tokens)
+        with span("aios.engine.readback", tracer=self.tracer):
+            tok_host = np.asarray(tokens)
         emitted: Dict[int, int] = {}
         for i in active:
             s = self.slots[i]
@@ -1198,10 +1224,9 @@ class ServingEngine:
         self.stats["decode_steps"] += 1
         self.stats["model_dispatches"] += 1
         self.stats["tokens"] += len(active)
-        if _t0:
-            self._obs_tick(KIND_DECODE, _t0, _t0, len(active),
-                           self.max_slots, 1, kvb, len(active),
-                           self.max_slots)
+        self._obs_tick(KIND_DECODE, _t0, _t0, len(active),
+                       self.max_slots, 1, kvb, len(active),
+                       self.max_slots)
         return emitted
 
     def serve_step(self) -> Dict[int, int]:
@@ -1221,8 +1246,19 @@ class ServingEngine:
         length-(1+m) chunk rows and the whole [pending, drafts] run is
         verified in that ONE model call. Ticks where no slot drafts keep
         the shape-stable pure-decode program -- the spec path costs nothing
-        when traffic is not repetitive."""
+        when traffic is not repetitive.
+
+        The tick is the ``aios.engine.serve_step`` span; the dispatch that
+        closes it labels it with its kind, rows and tokens."""
         self.last_tick_commits = {}
+        with span("aios.engine.serve_step", tracer=self.tracer) as sp:
+            self._tick_span = sp
+            try:
+                return self._serve_tick()
+            finally:
+                self._tick_span = None
+
+    def _serve_tick(self) -> Dict[int, int]:
         if not self.mixed:
             if self.prefill_pending():
                 self.prefill_step()
@@ -1232,8 +1268,9 @@ class ServingEngine:
         if self.spec:
             active = self.active_slots()
             if active:
-                drafts = self._propose_drafts(
-                    active, np.asarray(self.next_tokens))
+                with span("aios.engine.readback", tracer=self.tracer):
+                    pend_host = np.asarray(self.next_tokens)
+                drafts = self._propose_drafts(active, pend_host)
                 if drafts:
                     return self._mixed_dispatch(jobs, drafts=drafts)
         if not jobs:
@@ -1306,126 +1343,129 @@ class ServingEngine:
         _t0 = self._obs_t0()
         _t_build = _t0
         _kind = KIND_PADDED
-        if jobs:
-            rem = max(len(j.tokens) - j.done for j in jobs)
-            C = next((b for b in self.prefill_chunks if b >= rem),
-                     self.prefill_chunks[-1])
-        elif drafts:
-            # draft-only tick: the chunk axis only needs 1 + m_max slots --
-            # next power of two keeps the program count at log2(spec_k)
-            need = 1 + max(len(d) for d in drafts.values())
-            C = 1
-            while C < need:
-                C *= 2
-        else:
-            C = 1
-        for slot in list(drafts):   # a draft never outgrows the chunk row
-            drafts[slot] = drafts[slot][:C - 1]
-            if not drafts[slot]:
-                del drafts[slot]
-        part = [j.slot for j in jobs] + active
-        kb = 1
-        while kb < len(part):
-            kb *= 2
-        if kb >= self.max_slots:
-            kb = self.max_slots
-            idx = None                      # full batch: row == slot
-            row_of = {s: s for s in part}
-        else:
-            idx = list(part)
-            spare = [i for i in range(self.max_slots) if i not in set(idx)]
-            idx += spare[:kb - len(idx)]
-            row_of = {s: r for r, s in enumerate(part)}
-        if drafts and jobs and self.packed:
-            # draft-length budget vs prefill debt: when the tick carries
-            # prefill chunks, drafts ride free only if they don't push the
-            # packed token axis into a LARGER bucket -- prefill throughput
-            # (the paid-for debt) outranks speculative upside
-            al = kops.packed_row_align()
+        with span("aios.engine.build", tracer=self.tracer) as build:
+            if jobs:
+                rem = max(len(j.tokens) - j.done for j in jobs)
+                C = next((b for b in self.prefill_chunks if b >= rem),
+                         self.prefill_chunks[-1])
+            elif drafts:
+                # draft-only tick: the chunk axis only needs 1 + m_max slots --
+                # next power of two keeps the program count at log2(spec_k)
+                need = 1 + max(len(d) for d in drafts.values())
+                C = 1
+                while C < need:
+                    C *= 2
+            else:
+                C = 1
+            for slot in list(drafts):   # a draft never outgrows the chunk row
+                drafts[slot] = drafts[slot][:C - 1]
+                if not drafts[slot]:
+                    del drafts[slot]
+            part = [j.slot for j in jobs] + active
+            kb = 1
+            while kb < len(part):
+                kb *= 2
+            if kb >= self.max_slots:
+                kb = self.max_slots
+                idx = None                      # full batch: row == slot
+                row_of = {s: s for s in part}
+            else:
+                idx = list(part)
+                spare = [i for i in range(self.max_slots) if i not in set(idx)]
+                idx += spare[:kb - len(idx)]
+                row_of = {s: r for r, s in enumerate(part)}
+            if drafts and jobs and self.packed:
+                # draft-length budget vs prefill debt: when the tick carries
+                # prefill chunks, drafts ride free only if they don't push the
+                # packed token axis into a LARGER bucket -- prefill throughput
+                # (the paid-for debt) outranks speculative upside
+                al = kops.packed_row_align()
 
-            def _ptot(with_drafts: bool) -> int:
-                tot = 0
-                for j in jobs:
-                    n = min(len(j.tokens) - j.done, C)
-                    tot += -(-n // al) * al
-                for slot in active:
-                    n = 1 + (len(drafts.get(slot, ()))
-                             if with_drafts else 0)
-                    tot += -(-n // al) * al
-                return tot
+                def _ptot(with_drafts: bool) -> int:
+                    tot = 0
+                    for j in jobs:
+                        n = min(len(j.tokens) - j.done, C)
+                        tot += -(-n // al) * al
+                    for slot in active:
+                        n = 1 + (len(drafts.get(slot, ()))
+                                 if with_drafts else 0)
+                        tot += -(-n // al) * al
+                    return tot
 
-            b0 = next((b for b in _EngineJits.PACKED_BUCKETS
-                       if b >= max(_ptot(False), 1)), None)
-            b1 = next((b for b in _EngineJits.PACKED_BUCKETS
-                       if b >= max(_ptot(True), 1)), None)
-            if b0 is not None and b0 < kb * C and b1 != b0:
-                self.stats["spec_deferred"] += len(drafts)
-                drafts = {}
-        spec = bool(drafts)
-        upto = min(C, self.spec_k + 1) if spec else None
-        buf = np.zeros((kb, C), np.int32)
-        lengths = np.zeros((kb,), np.int32)
-        offsets = np.zeros((kb,), np.int32)
-        fresh = np.zeros((kb,), bool)
-        job_rows = []
-        for j in jobs:
-            r = row_of[j.slot]
-            n = min(len(j.tokens) - j.done, C)
-            buf[r, :n] = j.tokens[j.done:j.done + n]
-            lengths[r] = n
-            offsets[r] = j.done
-            fresh[r] = j.fresh and j.done == 0
-            job_rows.append((r, j, n))
-        if active:          # pure-prefill dispatches never sync the device
-            pend_host = np.asarray(self.next_tokens)
-        for slot in active:
-            r = row_of[slot]
-            s = self.slots[slot]
-            d = drafts.get(slot, ())
-            buf[r, 0] = pend_host[slot]
-            if d:
-                buf[r, 1:1 + len(d)] = d
-            lengths[r] = 1 + len(d)
-            offsets[r] = len(s.prompt) + len(s.generated)
-        max_end = min(self.max_len, int((offsets + lengths).max()))
-        kv = next(b for b in self.kv_buckets if b >= max_end)
-        if idx is None:
-            piece = self.cache
-        else:
-            idx_arr = jnp.asarray(np.asarray(idx, np.int32))
-            piece = self._gather_jit(self.cache, idx_arr)
-        if self.model.reset_fresh_rows and fresh.any():
-            piece = self._reset_jit(piece, self._cache_b1,
-                                    jnp.asarray(fresh))
-        img, imask = self._stack_images(
-            [(row_of[j.slot], j) for j in jobs], kb)
-        # token-packed ragged dispatch: when the real tokens fit a
-        # packed bucket smaller than the [kb, C] rectangle, issue them
-        # on one flat axis -- a decode row costs 1 token, a 7-token
-        # tail chunk costs 7, not C. Row segments are aligned to the
-        # packed kernel's q block on a Pallas backend so block rows
-        # never straddle two sequences; the gap slots carry zero pad
-        # tokens that the per-row length mask kills. Image rows join the
-        # packed axis too (their TEXT tokens pack; the frontend embeddings
-        # stay a per-row dense tensor -- padded-within-packed).
-        align = kops.packed_row_align()
-        row_starts = np.zeros((kb,), np.int32)
-        cur = 0
-        for r in range(kb):
-            row_starts[r] = cur
-            cur += -(-int(lengths[r]) // align) * align
-        Npb = next((b for b in _EngineJits.PACKED_BUCKETS
-                    if b >= max(cur, 1)), None)
-        use_packed = self.packed and Npb is not None and Npb < kb * C
-        pos_logits = None
-        if use_packed:
-            flat = np.zeros((Npb,), np.int32)
+                b0 = next((b for b in _EngineJits.PACKED_BUCKETS
+                           if b >= max(_ptot(False), 1)), None)
+                b1 = next((b for b in _EngineJits.PACKED_BUCKETS
+                           if b >= max(_ptot(True), 1)), None)
+                if b0 is not None and b0 < kb * C and b1 != b0:
+                    self.stats["spec_deferred"] += len(drafts)
+                    drafts = {}
+            spec = bool(drafts)
+            upto = min(C, self.spec_k + 1) if spec else None
+            buf = np.zeros((kb, C), np.int32)
+            lengths = np.zeros((kb,), np.int32)
+            offsets = np.zeros((kb,), np.int32)
+            fresh = np.zeros((kb,), bool)
+            job_rows = []
+            for j in jobs:
+                r = row_of[j.slot]
+                n = min(len(j.tokens) - j.done, C)
+                buf[r, :n] = j.tokens[j.done:j.done + n]
+                lengths[r] = n
+                offsets[r] = j.done
+                fresh[r] = j.fresh and j.done == 0
+                job_rows.append((r, j, n))
+            if active:          # pure-prefill dispatches never sync the device
+                with span("aios.engine.readback", tracer=self.tracer):
+                    pend_host = np.asarray(self.next_tokens)
+            for slot in active:
+                r = row_of[slot]
+                s = self.slots[slot]
+                d = drafts.get(slot, ())
+                buf[r, 0] = pend_host[slot]
+                if d:
+                    buf[r, 1:1 + len(d)] = d
+                lengths[r] = 1 + len(d)
+                offsets[r] = len(s.prompt) + len(s.generated)
+            max_end = min(self.max_len, int((offsets + lengths).max()))
+            kv = next(b for b in self.kv_buckets if b >= max_end)
+            if idx is None:
+                piece = self.cache
+            else:
+                idx_arr = jnp.asarray(np.asarray(idx, np.int32))
+                piece = self._gather_jit(self.cache, idx_arr)
+            if self.model.reset_fresh_rows and fresh.any():
+                piece = self._reset_jit(piece, self._cache_b1,
+                                        jnp.asarray(fresh))
+            img, imask = self._stack_images(
+                [(row_of[j.slot], j) for j in jobs], kb)
+            # token-packed ragged dispatch: when the real tokens fit a
+            # packed bucket smaller than the [kb, C] rectangle, issue them
+            # on one flat axis -- a decode row costs 1 token, a 7-token
+            # tail chunk costs 7, not C. Row segments are aligned to the
+            # packed kernel's q block on a Pallas backend so block rows
+            # never straddle two sequences; the gap slots carry zero pad
+            # tokens that the per-row length mask kills. Image rows join the
+            # packed axis too (their TEXT tokens pack; the frontend embeddings
+            # stay a per-row dense tensor -- padded-within-packed).
+            align = kops.packed_row_align()
+            row_starts = np.zeros((kb,), np.int32)
+            cur = 0
             for r in range(kb):
-                n = int(lengths[r])
-                if n:
-                    flat[row_starts[r]:row_starts[r] + n] = buf[r, :n]
-        if _t0:
-            _t_build = time.perf_counter()
+                row_starts[r] = cur
+                cur += -(-int(lengths[r]) // align) * align
+            Npb = next((b for b in _EngineJits.PACKED_BUCKETS
+                        if b >= max(cur, 1)), None)
+            use_packed = self.packed and Npb is not None and Npb < kb * C
+            pos_logits = None
+            if use_packed:
+                flat = np.zeros((Npb,), np.int32)
+                for r in range(kb):
+                    n = int(lengths[r])
+                    if n:
+                        flat[row_starts[r]:row_starts[r] + n] = buf[r, :n]
+            build.set(rows=len(part))
+            if _t0:
+                _t_build = time.perf_counter()
         if img is not None:
             _kind = KIND_IMAGE
             if use_packed:
@@ -1550,7 +1590,8 @@ class ServingEngine:
             n_acc_d, pend_d = self._spec_verify_jit(
                 pos_logits[srows], jnp.asarray(dbuf), jnp.asarray(m_arr),
                 self.seq_keys[ssl], self.counters[ssl])
-            n_acc = np.asarray(n_acc_d)
+            with span("aios.engine.readback", tracer=self.tracer):
+                n_acc = np.asarray(n_acc_d)
             self.next_tokens = self.next_tokens.at[ssl].set(pend_d)
             new_counters = []
             new_lens = []
@@ -1602,16 +1643,14 @@ class ServingEngine:
             for r, j in fin:
                 if j.image_embeds is not None:
                     continue   # token keys cannot name an image's K/V
-                piece1 = self._extract_jit(self.cache, j.slot)
-                self._cache_prefix(j.tokens, piece1, logits[r])
+                self._cache_prefix(j.tokens, logits[r], slot=j.slot)
         if fin:
             with self._lock:
                 done_set = {j.slot for _, j in fin}
                 self._prefill_queue = [jj for jj in self._prefill_queue
                                        if jj.slot not in done_set]
-        if _t0:
-            self._obs_tick(_kind, _t0, _t_build, len(part), kb, C, kv,
-                           int(lengths.sum()), kb * C)
+        self._obs_tick(_kind, _t0, _t_build, len(part), kb, C, kv,
+                       int(lengths.sum()), kb * C)
         return emitted
 
     def probe_failed_load(self, prompt) -> None:
@@ -1663,8 +1702,10 @@ class ServingEngine:
         seq_len = len(s.prompt) + len(s.generated)
         pending = int(self.next_tokens[slot])
         if kind == "logits":
-            piece = self._extract_jit(self.cache, slot)
-            leaves = [np.asarray(x) for x in jax.tree.leaves(piece)]
+            with span("aios.engine.extract", tracer=self.tracer) as ex:
+                piece = self._extract_jit(self.cache, slot)
+                leaves = [np.asarray(x) for x in jax.tree.leaves(piece)]
+                ex.set(bytes=sum(x.nbytes for x in leaves))
             if self.page_store is not None:
                 # suspend state enters the page table at the host tier: the
                 # pages covering a cached prefix of this context dedupe

@@ -28,6 +28,8 @@ from typing import Any, Optional
 
 import numpy as np
 
+from repro.obs import span
+
 
 class PrefixCache:
     """LRU over token-prefix -> snapshot. Values are duck-typed: anything
@@ -87,8 +89,10 @@ class PrefixCache:
         a prefix persisted by an earlier (or concurrent) process on the same
         root re-hydrates into the table instead of re-prefilling."""
         tok = np.asarray(tokens, np.int32)
-        with self._lock:
+        with self._lock, span("aios.prefix.lookup",
+                              tracer=self._tracer()) as sp:
             best_key, best = self._longest_prefix(tok)
+            rehydrated = 0
             if self.page_store is not None:
                 # probe the storage tier even on a resident hit: a SHORT
                 # resident prefix (e.g. the shared base) must not shadow a
@@ -97,6 +101,9 @@ class PrefixCache:
                     tok, longer_than=best.seq_len if best is not None else 0)
                 if rbest is not None:
                     best_key, best = rk, rbest
+                    rehydrated = 1
+            sp.set(hit_tokens=best.seq_len if best is not None else 0,
+                   rehydrated=rehydrated)
             if best is None:
                 self.stats["misses"] += 1
                 return None
@@ -112,6 +119,9 @@ class PrefixCache:
                 # be freed mid-read. The engine unpins after materializing.
                 pages._store.pin_pages(pages)
             return best
+
+    def _tracer(self):
+        return getattr(self.page_store, "tracer", None)
 
     def _rehydrate_locked(self, tok: np.ndarray, longer_than: int = 0):
         """Probe the page store's persisted manifests for a prefix of `tok`
@@ -187,29 +197,32 @@ class PrefixCache:
         Page-store entries are write-through persisted to the storage tier
         (unless they just came from it), so eviction -- and process death --
         never loses a hot prefix, only its RAM residency."""
-        if snap.seq_len < self.min_tokens:
-            return False
         nbytes = snap.nbytes()
-        if nbytes > self.budget_bytes:
-            return False
-        key = self.key_of(snap.prompt)
-        if (self.page_store is not None
-                and getattr(snap, "pages", None) is not None
-                and not getattr(snap, "_rehydrated", False)):
-            self.page_store.persist_prefix(snap)
-        with self._lock:
-            old = self._entries.pop(key, None)
-            if old is not None:
-                self._used -= old.nbytes()
-                self._release_entry(old)
-            self._entries[key] = snap
-            self._used += nbytes
-            self.stats["inserts"] += 1
-            while (self._used > self.budget_bytes or
-                   len(self._entries) > self.max_entries):
-                if not self._evict_one(protect=key):
-                    break
-            return True
+        with span("aios.prefix.insert", tracer=self._tracer(),
+                  bytes=nbytes) as sp:
+            accepted = (snap.seq_len >= self.min_tokens
+                        and nbytes <= self.budget_bytes)
+            sp.set(accepted=int(accepted))
+            if not accepted:
+                return False
+            key = self.key_of(snap.prompt)
+            if (self.page_store is not None
+                    and getattr(snap, "pages", None) is not None
+                    and not getattr(snap, "_rehydrated", False)):
+                self.page_store.persist_prefix(snap)
+            with self._lock:
+                old = self._entries.pop(key, None)
+                if old is not None:
+                    self._used -= old.nbytes()
+                    self._release_entry(old)
+                self._entries[key] = snap
+                self._used += nbytes
+                self.stats["inserts"] += 1
+                while (self._used > self.budget_bytes or
+                       len(self._entries) > self.max_entries):
+                    if not self._evict_one(protect=key):
+                        break
+                return True
 
     @staticmethod
     def _release_entry(snap):

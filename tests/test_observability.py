@@ -2,10 +2,13 @@
 exactly-once root spans, the unified metrics registry (legacy dict shape
 preserved as a view + Prometheus text), the per-tick engine profiler, the
 bounded audit/telemetry/trace rings, and tenant-namespaced storage paths."""
+import glob
 import json
 import time
 import urllib.request
 
+import jax
+import numpy as np
 import pytest
 
 from repro.control.telemetry import TelemetryBus
@@ -13,8 +16,11 @@ from repro.core import AIOSKernel
 from repro.core.access import AccessManager
 from repro.core.storage import StorageManager
 from repro.core.syscall import LLMSyscall, StorageSyscall
-from repro.obs import MetricsRegistry, TickProfiler, Tracer, serve_metrics
-from repro.obs.trace import PID_SYSCALLS
+from repro.configs import get_config
+from repro.obs import (MetricsRegistry, TickProfiler, Tracer, serve_metrics,
+                       span)
+from repro.obs.trace import PID_ENGINE, PID_SPANS, PID_SYSCALLS
+from repro.serving import engine as engine_mod
 from repro.sdk.api import AgentSession
 from repro.sdk.query import LLMQuery, StorageQuery
 
@@ -201,6 +207,125 @@ class TestChromeTraceExport:
         tr = Tracer(enabled=False)
         tr.instant("x", 1, 1)
         assert tr.events() == []
+
+
+# ---------------------------------------------------------------------------
+# serving-path spans: TraceAnnotations on the profiler's clock, mirrored
+# into the tracer when one is given
+# ---------------------------------------------------------------------------
+def _profile(log_dir, fn):
+    """Run ``fn`` inside a jax.profiler session; return the session's
+    ``aios.*`` events as (line, name, start_ns, end_ns, stats) and the
+    session's start on the realtime clock."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(glob.glob(
+        str(log_dir / "**" / "*.xplane.pb"), recursive=True)[0])
+    evs, start = [], None
+    for plane in pd.planes:
+        stats = dict(plane.stats)
+        if "profile_start_time" in stats:
+            start = stats["profile_start_time"]
+        for line in plane.lines:
+            evs += [(line.name, e.name, e.start_ns, e.end_ns, dict(e.stats))
+                    for e in line.events if e.name.startswith("aios.")]
+    return evs, start
+
+
+class TestServingSpans:
+    def test_span_without_tracer_records_nothing_there(self):
+        tr = Tracer()
+        with span("aios.test", pid=7, rows=2) as sp:
+            sp.set(tokens=3)
+        assert tr.events() == []
+
+    def test_span_with_tracer_records_one_x_event_and_nests(self):
+        tr = Tracer()
+        with span("aios.outer", tracer=tr, pid=7, rows=2) as sp:
+            with span("aios.inner", tracer=tr):
+                time.sleep(0.002)
+            sp.set(tokens=3)
+        xs = {e["name"]: e for e in tr.events() if e["ph"] == "X"}
+        assert set(xs) == {"aios.outer", "aios.inner"}
+        out, inn = xs["aios.outer"], xs["aios.inner"]
+        assert out["args"] == {"pid": 7, "rows": 2, "tokens": 3}
+        assert "args" not in inn
+        assert (out["pid"], out["tid"]) == (inn["pid"], inn["tid"])
+        assert out["pid"] == PID_SPANS
+        assert out["ts"] <= inn["ts"]
+        assert inn["ts"] + inn["dur"] <= out["ts"] + out["dur"]
+        assert inn["dur"] >= 2000
+
+    @pytest.mark.parametrize("prompt_len,kind", [(6, "decode"),
+                                                 (40, "packed")])
+    def test_engine_tick_span_starts_at_the_tick_t0(self, monkeypatch,
+                                                    prompt_len, kind):
+        tr = Tracer()
+        eng = engine_mod.ServingEngine(
+            get_config("tiny"), max_slots=2, max_len=64, tracer=tr,
+            profiler=TickProfiler(), prefill_chunk_cap=16)
+        slot = eng.add_sequence(np.arange(1, 7, dtype=np.int32), max_new=8)
+        if kind == "packed":
+            eng.add_sequence(np.arange(1, prompt_len + 1, dtype=np.int32),
+                             max_new=2, eager=False)
+        eng.serve_step()                      # compile outside the stamps
+        stamps = []
+
+        def clock(base=time.time_ns()):
+            stamps.append(base + 1_000_000 * len(stamps))
+            return stamps[-1]
+
+        monkeypatch.setattr(engine_mod, "now_ns", clock)
+        n = len(tr.events())
+        eng.serve_step()
+        assert eng.slots[slot].active
+        tick = [e for e in tr.events()[n:] if e["name"] == "tick"]
+        assert len(tick) == 1 and tick[0]["args"]["kind"] == kind
+        assert tick[0]["pid"] == PID_ENGINE
+        # one stamp on the tracer's clock, at t0; the duration is the
+        # profiler's wall for the same tick
+        assert len(stamps) == 1
+        assert tick[0]["ts"] == pytest.approx(tr.us(stamps[0]))
+        wall = eng.profiler._wall[(eng.profiler.n - 1) % eng.profiler.cap]
+        assert tick[0]["dur"] == pytest.approx(wall * 1e6)
+        step = [e for e in tr.events()[n:]
+                if e["name"] == "aios.engine.serve_step"]
+        assert len(step) == 1 and step[0]["args"]["kind"] == kind
+
+    def test_kernel_spans_land_in_a_profiler_trace(self, tkernel, tmp_path):
+        def serve_two():
+            scs = [AgentSession(tkernel, f"xplane{i}").submit(
+                LLMQuery(prompt=[40 + i] + PROMPT, max_new_tokens=5))
+                for i in range(2)]
+            for sc in scs:
+                sc.join(timeout=60)
+
+        evs, start = _profile(tmp_path, serve_two)
+        names = {e[1] for e in evs}
+        assert {"aios.engine.serve_step", "aios.core.finish",
+                "aios.prefix.store", "aios.pagestore.put"} <= names
+        finishes = [e for e in evs if e[1] == "aios.core.finish"]
+        assert len(finishes) >= 2
+        assert all("pid" in e[4] and "tokens" in e[4] for e in finishes)
+        stores = [e for e in evs if e[1] == "aios.prefix.store"]
+        for line, _, s, t, _ in (e for e in evs
+                                 if e[1] == "aios.pagestore.put"):
+            assert any(sl == line and ss <= s and t <= st
+                       for sl, _, ss, st, _ in stores)
+        # the tracer saw the same spans: after the fixed shift between its
+        # origin and the session's start, each lines up with its own
+        shift = tkernel.tracer.origin_ns - start
+        mirrored = [shift + e["ts"] * 1e3 for e in tkernel.tracer.events()
+                    if e["name"] == "aios.engine.serve_step"]
+        for e in evs:
+            if e[1] == "aios.engine.serve_step":
+                assert min(abs(m - e[2]) for m in mirrored) < 1e6
 
 
 # ---------------------------------------------------------------------------

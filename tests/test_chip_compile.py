@@ -1,6 +1,8 @@
 """Ahead-of-time compiles of the four serving attention kernels for a
 described TPU v5e, at yi-6b serving widths (B=8 slots, S=2048 positions,
-H=32 query heads, K=4 kv heads, hd=128, bf16).
+H=32 query heads, K=4 kv heads, hd=128, bf16); of the one-token kernel at
+K = 8, 4 and 32 kv heads; and of the pure-decode program of a 2-layer
+model at granite-3.0-8b widths.
 
 Nothing runs: the TPU compiler that ships with jaxlib compiles for a chip
 that is described, not attached, and refuses what the chip would refuse
@@ -9,6 +11,7 @@ mode cannot show. The topology is described inside a fixture, never at
 import time, so every xdist worker collects the same tests and only the
 worker that runs this file loads the TPU library."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,3 +89,64 @@ def test_kernel_compiles_for_v5e(one_chip, build):
     fn, args = build(one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _custom_calls(hlo):
+    """Instruction names of the Pallas kernels in a compiled program."""
+    return re.findall(r"%([\w.-]+) = \S+ custom-call\([^\n]*"
+                      r'custom_call_target="tpu_custom_call"', hlo)
+
+
+@pytest.mark.parametrize("kv_heads", [8, 4, 32])
+def test_one_token_chunk_compiles_for_v5e(one_chip, kv_heads):
+    """The C == 1 kernel at 8 slots x 4096 positions, for granite's 8 kv
+    heads, yi's 4 and an MHA cache of 32: the (bk, K, hd) blocks that
+    ``decode_block_k`` picks fit the core's scoped VMEM, and the kernel
+    reads the [B, S, K, hd] cache as it lies, with no transposed copy."""
+    S_, K_ = 4096, kv_heads
+    fn = lambda q, k, v, off, ql: da.chunk_attention(q, k, v, off, ql)
+    args = (_spec(one_chip, (B, 1, H, HD)), _spec(one_chip, (B, S_, K_, HD)),
+            _spec(one_chip, (B, S_, K_, HD)), _spec(one_chip, (B,), jnp.int32),
+            _spec(one_chip, (B,), jnp.int32))
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    calls = _custom_calls(hlo)
+    assert calls and all(c.startswith("chunk_attention_decode")
+                         for c in calls), calls
+    assert f"[{B},{K_},{S_},{HD}]" not in hlo
+
+
+def test_mixed_decode_reads_cache_in_place(one_chip):
+    """The pure-decode tick of a 2-layer model at granite-3.0-8b widths
+    (8 slots x 4096, 32 heads on 8 kv heads): its attention is the
+    one-token kernel, named so that the benchmark's roofline reader
+    (``^chunk_attention``) finds it, no [B, K, S, hd] transposed copy of a
+    cache layer is built for it, and no cache layer is placed in VMEM."""
+    from repro.configs.granite_3_8b import CONFIG
+    from repro.kernels import ops as kops
+    from repro.serving import engine as engine_mod
+
+    slots, max_len = 8, 4096
+    cfg = CONFIG.replace(num_layers=2)
+    spec = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+    kops.set_backend("tpu")
+    try:
+        js = engine_mod._EngineJits(cfg, 0.0)
+        params = spec(jax.eval_shape(
+            lambda k: js.model.init_params(k)[0], jax.random.key(0)))
+        cache = spec(jax.eval_shape(
+            lambda: js.model.init_cache(slots, max_len)[0]))
+        hlo = js.mixed_decode.lower(
+            params, _spec(one_chip, (slots,), jnp.int32), cache,
+            _spec(one_chip, (slots,), jnp.bool_), kv=max_len
+        ).compile().as_text()
+    finally:
+        kops.set_backend(None)
+    calls = _custom_calls(hlo)
+    assert calls and all(c.startswith("chunk_attention") for c in calls), calls
+    K_ = cfg.n_kv_heads
+    assert f"[{slots},{K_},{max_len},{cfg.head_dim}]" not in hlo
+    # the kernel reads its layer from HBM, not from a copy staged in VMEM
+    # (memory space S(1))
+    layer = rf"\[{slots},{max_len},{K_},{cfg.head_dim}\]\{{[^}}]*S\(1\)\}}"
+    assert not re.search(layer, hlo)

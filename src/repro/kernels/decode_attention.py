@@ -31,7 +31,9 @@ with one pair of products (each kv head's g = H/K heads from the block it
 fetched), so each live K/V byte is read once per tick. The kv index map
 clamps to the row's live blocks, so blocks past its position (or before its
 window) are not fetched at all: the cost follows the context, not the kv
-bucket.
+bucket. It also reads one layer of a whole [L, B, S, K, hd] stack by a
+traced index, which is how a model's pure-decode layer loop hands it the
+cache it carries.
 """
 from __future__ import annotations
 
@@ -120,11 +122,13 @@ def decode_block_k(S: int, K: int, hd: int, itemsize: int) -> int:
     return min(bk, S)
 
 
-def _decode_kernel(off_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(off_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale: float, bk: int, nk: int,
                    S: int, g: int, window: int):
+    del layer_ref                           # read by the kv index map only
     b = pl.program_id(0)
     ki = pl.program_id(1)
+    k_ref, v_ref = k_ref.at[0], v_ref.at[0]  # the (1, bk, K, hd) block
     _, _, K, hd = k_ref.shape
     H = q_ref.shape[2]
 
@@ -184,8 +188,8 @@ def _decode_kernel(off_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k", "interpret"))
-def chunk_attention_decode(q, k_cache, v_cache, q_offsets, q_lens, *,
-                           window: int = 0, block_k=None,
+def chunk_attention_decode(q, k_cache, v_cache, q_offsets, q_lens,
+                           layer=None, *, window: int = 0, block_k=None,
                            interpret: bool = False):
     """The C == 1 case of ``chunk_attention`` (q [B, 1, H, hd]), reading
     the [B, S, K, hd] caches in place: grid (B, S/bk), one (bk, K, hd) K/V
@@ -194,9 +198,17 @@ def chunk_attention_decode(q, k_cache, v_cache, q_offsets, q_lens, *,
     at least its window's first -- so the pipeline fetches no dead block (an
     unchanged block index is not fetched again); rows with q_len == 0 fetch
     one block and write zeros. Without ``block_k``, bk comes from
-    ``decode_block_k``. Returns [B, 1, H, hd]."""
+    ``decode_block_k``. Returns [B, 1, H, hd].
+
+    Given a traced ``layer``, the caches are whole layer stacks
+    [L, B, S, K, hd] and the kernel reads layer ``layer`` of them where it
+    lies: the index rides in SMEM by scalar prefetch into the K/V index
+    map, so a model's layer loop can carry the stacks and hand them over
+    without slicing a layer out. A single cache is the L == 1 case."""
+    if layer is None:
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
     B, _, H, hd = q.shape
-    _, S, K, _ = k_cache.shape
+    _, _, S, K, _ = k_cache.shape
     assert H % K == 0
     # the block is read as bk*K rows: K > 1 rows of a position must fill
     # whole 32-bit sublanes, or VMEM pads them apart
@@ -205,14 +217,14 @@ def chunk_attention_decode(q, k_cache, v_cache, q_offsets, q_lens, *,
         S, K, hd, k_cache.dtype.itemsize)
     nk = -(-S // bk)
 
-    def kv_map(b, ki, of, ql):
+    def kv_map(b, ki, of, ql, ly):
         last = jnp.where(ql[b] > 0,
                          jnp.minimum(jnp.maximum(of[b], 0) // bk, nk - 1), 0)
         idx = jnp.minimum(ki, last)
         if window:
             first = jnp.maximum(of[b] - window + 1, 0) // bk
             idx = jnp.maximum(idx, jnp.minimum(first, last))
-        return (b, idx, 0, 0)
+        return (ly[0], b, idx, 0, 0)
 
     if not interpret:
         # the kernel streams the cache from HBM, where it lies. Left free,
@@ -226,15 +238,16 @@ def chunk_attention_decode(q, k_cache, v_cache, q_offsets, q_lens, *,
         _decode_kernel, scale=1.0 / math.sqrt(hd), bk=bk, nk=nk, S=S,
         g=H // K, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, H, hd), lambda b, ki, of, ql: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bk, K, hd), kv_map),
-            pl.BlockSpec((1, bk, K, hd), kv_map),
+            pl.BlockSpec((1, 1, H, hd),
+                         lambda b, ki, of, ql, ly: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, bk, K, hd), kv_map),
+            pl.BlockSpec((1, 1, bk, K, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, H, hd),
-                               lambda b, ki, of, ql: (b, 0, 0, 0)),
+                               lambda b, ki, of, ql, ly: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -248,14 +261,14 @@ def chunk_attention_decode(q, k_cache, v_cache, q_offsets, q_lens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q_offsets.astype(jnp.int32), q_lens.astype(jnp.int32), q, k_cache,
-      v_cache)
+    )(q_offsets.astype(jnp.int32), q_lens.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q, k_cache, v_cache)
 
 
 @functools.partial(
     jax.jit, static_argnames=("window", "block_q", "block_k", "interpret"))
-def chunk_attention(q, k_cache, v_cache, q_offsets, q_lens=None, *,
-                    window: int = 0, block_q: int = 128, block_k=None,
+def chunk_attention(q, k_cache, v_cache, q_offsets, q_lens=None, layer=None,
+                    *, window: int = 0, block_q: int = 128, block_k=None,
                     interpret: bool = False):
     """q: [B, C, H, hd]; caches [B, S, K, hd]; q_offsets [B] (absolute
     position of each sequence's chunk row 0; the chunk's own K/V must already
@@ -264,18 +277,20 @@ def chunk_attention(q, k_cache, v_cache, q_offsets, q_lens=None, *,
     blocks are bounded by the row's valid end, so mixed batches of prefill
     (q_len == C), decode (q_len == 1) and inactive (q_len == 0) rows each pay
     their own cost. Rows past q_len inside a live q block are garbage.
-    One-token chunks (C == 1) run ``chunk_attention_decode``; without
+    One-token chunks (C == 1) run ``chunk_attention_decode``, which also
+    takes [L, B, S, K, hd] stacks and a traced ``layer``; without
     ``block_k`` longer chunks take 256-position kv blocks.
     Returns [B, C, H, hd]."""
     B, C, H, hd = q.shape
-    _, S, K, _ = k_cache.shape
-    assert H % K == 0
     if q_lens is None:
         q_lens = jnp.full((B,), C, jnp.int32)
     if C == 1:
         return chunk_attention_decode(q, k_cache, v_cache, q_offsets, q_lens,
-                                      window=window, block_k=block_k,
+                                      layer, window=window, block_k=block_k,
                                       interpret=interpret)
+    assert layer is None, "a layer of a stack is read one token at a time"
+    _, S, K, _ = k_cache.shape
+    assert H % K == 0
     bq = min(block_q, C)
     bk = min(block_k or 256, S)
     C_pad = ((C + bq - 1) // bq) * bq

@@ -47,19 +47,22 @@ def flash_attention(q, k, v, *, q_offset=0, window=0, q_offsets=None,
 
 
 def chunk_attention(q, k_cache, v_cache, q_offsets, q_lens=None, *, window=0,
-                    backend=None, **kw):
+                    layer=None, backend=None, **kw):
     """Chunked-prefill attention: q [B, C, H, hd] at per-sequence offsets
     against a contiguous KV cache (prefix+chunk causal mask). Per-row
     ``q_lens`` admits mixed batches -- prefill (q_len == C), decode
     (q_len == 1) and inactive (q_len == 0) rows in ONE dispatch, each
-    paying only its own q/kv blocks."""
+    paying only its own q/kv blocks. With a traced ``layer`` (C == 1 only)
+    the caches are [L, B, S, K, hd] stacks and layer ``layer`` is read."""
     b = backend or default_backend()
     if b == "jnp":
+        if layer is not None:
+            k_cache, v_cache = k_cache[layer], v_cache[layer]
         return _ref.chunk_attention_ref(q, k_cache, v_cache, q_offsets,
                                         q_lens, window=window)
     return _da.chunk_attention(q, k_cache, v_cache, q_offsets, q_lens,
-                               window=window, interpret=(b == "interpret"),
-                               **kw)
+                               layer, window=window,
+                               interpret=(b == "interpret"), **kw)
 
 
 def packed_chunk_attention(q, k_cache, v_cache, row_starts, q_offsets,

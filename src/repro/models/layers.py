@@ -160,7 +160,7 @@ def _attn_block(q, k, v, q_pos, kv_pos, scale, window):
 
 
 def chunk_attention(q, k_cache, v_cache, q_offsets, *, q_lens=None,
-                    window: int = 0):
+                    window: int = 0, layer=None):
     """Prefix+chunk causal attention (chunked prefill): query row i of
     sequence b sits at absolute position ``q_offsets[b] + i`` and attends to
     cache positions ``0 .. q_offsets[b] + i`` (optionally sliding-window).
@@ -176,10 +176,16 @@ def chunk_attention(q, k_cache, v_cache, q_offsets, *, q_lens=None,
     writes instead). Off the jnp backend this is the Pallas kernel
     (kernels/decode_attention.chunk_attention); the jnp path below has
     identical semantics for the valid rows.
+
+    ``layer`` (traced, C == 1 only): the caches are [L, B, S, K, hd] layer
+    stacks and attention reads layer ``layer`` -- the Pallas kernel where
+    it lies in the stack, the jnp path from ``stack[layer]``.
     """
     if kops.default_backend() != "jnp":
         return kops.chunk_attention(q, k_cache, v_cache, q_offsets, q_lens,
-                                    window=window)
+                                    window=window, layer=layer)
+    if layer is not None:
+        k_cache, v_cache = k_cache[layer], v_cache[layer]
     B, C, H, hd = q.shape
     S, K = k_cache.shape[1], k_cache.shape[2]
     g = H // K
@@ -354,6 +360,21 @@ def cache_write_chunk(cache, new, offsets, lengths):
     src = jnp.take_along_axis(new, jnp.clip(idx, 0, C - 1)[:, :, None, None],
                               axis=1)
     return jnp.where(hit[:, :, None, None], src.astype(cache.dtype), cache)
+
+
+def cache_write_stacked_token(stack, new, layer, offsets, lengths):
+    """Write one token per sequence into layer ``layer`` of a whole
+    [L, B, S, K, hd] cache stack, in place: ``new[b, 0]`` lands at
+    ``stack[layer, b, offsets[b]]``. A scatter of B positions, so no layer
+    is read or rewritten: rows with ``lengths[b] == 0`` are sent to row B,
+    past the stack, and dropped, as is a position at or past S, so they
+    stay untouched bit for bit (what cache_write_chunk's select gives).
+    The serving cache this writes is not sharded along its sequence axis.
+    new: [B, 1, K, hd]; layer: traced scalar; offsets, lengths: [B]."""
+    B = new.shape[0]
+    rows = jnp.where(lengths > 0, jnp.arange(B), B)
+    return stack.at[layer, rows, offsets].set(new[:, 0].astype(stack.dtype),
+                                              mode="drop")
 
 
 def cache_write_packed(cache, new, rows, pos, valid):

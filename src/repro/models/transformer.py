@@ -320,6 +320,14 @@ class DenseTransformer:
         decoding, where a decode row carries [pending, draft_1..draft_m]
         and the engine needs the model's distribution at EACH position to
         run acceptance. Return becomes (cache, last_logits, pos_logits).
+
+        One-token chunks (C == 1, the pure-decode tick) without
+        ``logits_upto`` take ``_decode_layers``: the layer loop carries the
+        whole K/V stacks, writes each row's token in place and attention
+        reads its layer out of the stack, so no cache layer is sliced,
+        rewritten or copied; ``kv_width`` is not needed there (attention
+        reads each row's live blocks only). The VLM keeps the per-layer
+        path below: its self layers sit in a nested scan.
         """
         cfg = self.cfg
         B, C = tokens.shape
@@ -343,7 +351,11 @@ class DenseTransformer:
             x = x + L.attn_out(blk["attn"], o)
             return self._ffn(blk, x, infer=True), kc, vc
 
-        if self.is_vlm:
+        if C == 1 and not self.is_vlm and logits_upto is None:
+            x, kn, vn = self._decode_layers(params, x, cache["k"], cache["v"],
+                                            positions, q_offset, lengths)
+            cache = dict(cache, k=kn, v=vn)
+        elif self.is_vlm:
             has_img = lengths > 0
             if image_mask is not None:
                 has_img &= image_mask
@@ -406,6 +418,33 @@ class DenseTransformer:
                     * cfg.logits_softcap
             return cache, last_logits, pos_logits
         return cache, last_logits
+
+    def _decode_layers(self, params, x, k_stack, v_stack, positions,
+                       q_offset, lengths):
+        """The layer loop of a one-token chunk (x [B, 1, d]) with the whole
+        [L, B, S, K, hd] K/V stacks in the scan's carry: layer l writes row
+        b's token at (l, b, q_offset[b]) by a scatter on the carried stack
+        (rows with lengths == 0 dropped) and attention reads layer l where
+        it lies. The stacks are neither scanned over nor stacked back, so
+        the program updates them in place. Returns (x, k_stack, v_stack)."""
+        cfg = self.cfg
+
+        def body(carry, xs):
+            x, ks, vs = carry
+            blk, layer = xs
+            h = L.rms_norm(x, blk["ln1"], cfg.norm_eps)
+            q, k, v = L.attn_qkv(blk["attn"], h, cfg, positions)
+            ks = L.cache_write_stacked_token(ks, k, layer, q_offset, lengths)
+            vs = L.cache_write_stacked_token(vs, v, layer, q_offset, lengths)
+            o = L.chunk_attention(q, ks, vs, q_offset, q_lens=lengths,
+                                  layer=layer)
+            x = x + L.attn_out(blk["attn"], o)
+            return (self._ffn(blk, x, infer=True), ks, vs), None
+
+        (x, k_stack, v_stack), _ = L.xscan(
+            _remat(body, cfg.remat_policy), (x, k_stack, v_stack),
+            (params["blocks"], jnp.arange(self.n_super, dtype=jnp.int32)))
+        return x, k_stack, v_stack
 
     # -- token-packed ragged prefill -------------------------------------------
     def prefill_packed(self, params, tokens, cache, *, row_starts, q_offset,

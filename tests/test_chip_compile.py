@@ -119,8 +119,12 @@ def test_mixed_decode_reads_cache_in_place(one_chip):
     """The pure-decode tick of a 2-layer model at granite-3.0-8b widths
     (8 slots x 4096, 32 heads on 8 kv heads): its attention is the
     one-token kernel, named so that the benchmark's roofline reader
-    (``^chunk_attention``) finds it, no [B, K, S, hd] transposed copy of a
-    cache layer is built for it, and no cache layer is placed in VMEM."""
+    (``^chunk_attention``) finds it, and the layer loop updates the K/V
+    stacks where they lie. No op builds a cache layer -- no [B, K, S, hd]
+    transposed copy, no [B, S, K, hd] slice, select or write-back -- no
+    stack is copied or given a fresh buffer (``AllocateBuffer``), no cache
+    is placed in VMEM, and the program's temporaries stay under one cache
+    layer."""
     from repro.configs.granite_3_8b import CONFIG
     from repro.kernels import ops as kops
     from repro.serving import engine as engine_mod
@@ -136,17 +140,23 @@ def test_mixed_decode_reads_cache_in_place(one_chip):
             lambda k: js.model.init_params(k)[0], jax.random.key(0)))
         cache = spec(jax.eval_shape(
             lambda: js.model.init_cache(slots, max_len)[0]))
-        hlo = js.mixed_decode.lower(
+        compiled = js.mixed_decode.lower(
             params, _spec(one_chip, (slots,), jnp.int32), cache,
-            _spec(one_chip, (slots,), jnp.bool_), kv=max_len
-        ).compile().as_text()
+            _spec(one_chip, (slots,), jnp.bool_), kv=max_len).compile()
     finally:
         kops.set_backend(None)
+    hlo = compiled.as_text()
     calls = _custom_calls(hlo)
     assert calls and all(c.startswith("chunk_attention") for c in calls), calls
-    K_ = cfg.n_kv_heads
-    assert f"[{slots},{K_},{max_len},{cfg.head_dim}]" not in hlo
-    # the kernel reads its layer from HBM, not from a copy staged in VMEM
+    K_, hd = cfg.n_kv_heads, cfg.head_dim
+    layer_bytes = slots * max_len * K_ * hd * 2
+    assert f"[{slots},{K_},{max_len},{hd}]" not in hlo
+    assert f"[{slots},{max_len},{K_},{hd}]" not in hlo
+    assert "AllocateBuffer" not in hlo
+    stack = rf"\[{cfg.num_layers},{slots},{max_len},{K_},{hd}\]"
+    copies = re.findall(rf"= bf16{stack}\S* copy(?:-start)?\(", hlo)
+    assert not copies, copies
+    # the kernel reads its stack from HBM, not from a copy staged in VMEM
     # (memory space S(1))
-    layer = rf"\[{slots},{max_len},{K_},{cfg.head_dim}\]\{{[^}}]*S\(1\)\}}"
-    assert not re.search(layer, hlo)
+    assert not re.search(rf"{stack}\{{[^}}]*S\(1\)\}}", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes

@@ -257,15 +257,22 @@ def test_chunk_attention_ignores_stale_cache_tail():
     np.testing.assert_allclose(base, out, atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("window", [0, 40])
-@pytest.mark.parametrize("H,K", [(32, 8), (32, 4), (8, 8), (32, 1)])
-def test_one_token_chunk_reads_cache_in_place(H, K, window):
+@pytest.mark.parametrize("H,K,window,layers", [
+    pytest.param(H, K, w, None, id=f"{H}-{K}-{w}")
+    for H, K in [(32, 8), (32, 4), (8, 8), (32, 1)] for w in (0, 40)] + [
+    pytest.param(H, K, 0, 3, id=f"{H}-{K}-0-stack3")
+    for H, K in [(32, 8), (32, 4), (32, 1)]])
+def test_one_token_chunk_reads_cache_in_place(H, K, window, layers):
     """The C == 1 kernel (grid over rows and kv blocks of the [B, S, K, hd]
     cache as it lies, all heads per block, dead blocks not fetched) against
     both references, at the block size it picks for the shape: queries at
     positions 0, bk-1, bk and S-1, a cache whose S is not a multiple of bk,
     and an inactive row. Garbage in the cache outside each row's window --
-    past its position, or before its sliding window -- changes nothing."""
+    past its position, or before its sliding window -- changes nothing.
+    With ``layers``, the caches are [L, B, S, K, hd] stacks and the kernel
+    reads layer 0, a middle layer and L-1 by index: each equals the
+    references on that layer, and garbage in the other layers changes
+    nothing."""
     from repro.kernels import decode_attention as da
     hd = 128
     bk = da.decode_block_k(1 << 20, K, hd, 4)
@@ -274,28 +281,40 @@ def test_one_token_chunk_reads_cache_in_place(H, K, window):
     offs = jnp.array([0, bk - 1, bk, S - 1, S // 2], jnp.int32)
     qlens = jnp.array([1, 1, 1, 1, 0], jnp.int32)
     B = offs.shape[0]
+    shape = (B, S, K, hd) if layers is None else (layers, B, S, K, hd)
     ks = jax.random.split(jax.random.key(24), 4)
     q = _rand(ks[0], (B, 1, H, hd), jnp.float32)
-    kc = _rand(ks[1], (B, S, K, hd), jnp.float32)
-    vc = _rand(ks[2], (B, S, K, hd), jnp.float32)
-    out = ops.chunk_attention(q, kc, vc, offs, qlens, window=window,
-                              backend="interpret")
-    exp = ref.chunk_attention_ref(q, kc, vc, offs, qlens, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
-                               atol=TOL[jnp.float32], rtol=TOL[jnp.float32])
-    dec = ref.decode_attention_ref(q[:, 0], kc, vc, offs + 1, window=window)
-    np.testing.assert_allclose(np.asarray(out)[:4, 0], np.asarray(dec)[:4],
-                               atol=TOL[jnp.float32], rtol=TOL[jnp.float32])
-    assert np.all(np.asarray(out)[4] == 0)
+    kc = _rand(ks[1], shape, jnp.float32)
+    vc = _rand(ks[2], shape, jnp.float32)
+    noise = _rand(ks[3], shape, jnp.float32) * 100
     pos = jnp.arange(S)[None, :, None, None]
     dead = pos > offs[:, None, None, None]
     if window:
         dead |= pos <= (offs - window)[:, None, None, None]
-    noise = _rand(ks[3], (B, S, K, hd), jnp.float32) * 100
-    stale = ops.chunk_attention(q, jnp.where(dead, noise, kc),
-                                jnp.where(dead, noise, vc), offs, qlens,
-                                window=window, backend="interpret")
-    np.testing.assert_array_equal(np.asarray(stale), np.asarray(out))
+    for layer in [None] if layers is None else [0, layers // 2, layers - 1]:
+        def attend(k, v, layer=layer):
+            return ops.chunk_attention(q, k, v, offs, qlens, window=window,
+                                       layer=layer, backend="interpret")
+        kl, vl, off_layer = kc, vc, dead
+        if layer is not None:
+            kl, vl = kc[layer], vc[layer]
+            off_layer = dead[None] | (jnp.arange(layers) != layer)[
+                :, None, None, None, None]
+        out = attend(kc, vc)
+        exp = ref.chunk_attention_ref(q, kl, vl, offs, qlens, window=window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                                   atol=TOL[jnp.float32],
+                                   rtol=TOL[jnp.float32])
+        dec = ref.decode_attention_ref(q[:, 0], kl, vl, offs + 1,
+                                       window=window)
+        np.testing.assert_allclose(np.asarray(out)[:4, 0],
+                                   np.asarray(dec)[:4],
+                                   atol=TOL[jnp.float32],
+                                   rtol=TOL[jnp.float32])
+        assert np.all(np.asarray(out)[4] == 0)
+        stale = attend(jnp.where(off_layer, noise, kc),
+                       jnp.where(off_layer, noise, vc))
+        np.testing.assert_array_equal(np.asarray(stale), np.asarray(out))
 
 
 def test_flash_attention_per_sequence_offsets_and_kv_lens():

@@ -170,3 +170,86 @@ def test_param_counts_match_published_scale():
     for arch, target in expect.items():
         n = get_config(arch).param_count()
         assert 0.55 * target < n < 1.45 * target, (arch, n, target)
+
+
+def _per_layer_decode_tick(model, params, tokens, cache, q_offset, lengths):
+    """The one-token tick one layer at a time, the way the chunk path runs
+    it: a scan over the layers and the K/V stacks, each step writing the
+    token into its layer with ``cache_write_chunk`` (a select over the
+    layer) and attending over that layer, the layers stacked back as the
+    scan's output. (A scan, as the model's own loop is: the same ops
+    unrolled fuse differently and round bf16 differently.) Returns
+    (k, v, logits)."""
+    from repro.models import layers as L
+    cfg = model.cfg
+    x = params["embed"][tokens].astype(cfg.dtype)
+    positions = q_offset[:, None]
+
+    def layer(x, xs):
+        blk, kc, vc = xs
+        h = L.rms_norm(x, blk["ln1"], cfg.norm_eps)
+        q, k, v = L.attn_qkv(blk["attn"], h, cfg, positions)
+        kc = L.cache_write_chunk(kc, k, q_offset, lengths)
+        vc = L.cache_write_chunk(vc, v, q_offset, lengths)
+        o = L.chunk_attention(q, kc, vc, q_offset, q_lens=lengths)
+        x = model._ffn(blk, x + L.attn_out(blk["attn"], o), infer=True)
+        return x, (kc, vc)
+
+    x, (k, v) = jax.lax.scan(layer, x,
+                             (params["blocks"], cache["k"], cache["v"]))
+    x = L.rms_norm(x, params["lnf"], cfg.norm_eps)
+    return k, v, x[:, 0] @ params["head"]
+
+
+@pytest.mark.parametrize("rows", ["edges", "narrow"])
+@pytest.mark.parametrize("backend", ["jnp", "interpret"])
+@pytest.mark.parametrize("arch", ["granite-3-8b", "arctic-480b"])
+def test_decode_tick_writes_carried_stack_in_place(arch, backend, rows):
+    """The pure-decode tick (``prefill_chunk`` with C == 1) carries the
+    whole K/V stacks through its layer loop, writes each row's token in
+    place and reads each layer out of the stack. It must equal the
+    per-layer tick bit for bit -- logits and both stacks -- for a dense
+    GQA and an MoE model, on the jnp path and through the Pallas kernel:
+    rows at positions 0 and S-1, a row with lengths == 0 (its cache left
+    as it was, bit for bit) and, with ``kv_width`` < S, a narrow bucket
+    the carried path has no need of."""
+    from repro.kernels import ops as kops
+    cfg = get_config(arch, smoke=True).replace(num_layers=3)
+    model = build_model(cfg)
+    params, _ = model.init_params(jax.random.key(0))
+    B, S = 4, 64
+    if rows == "edges":
+        q_offset = jnp.array([0, S - 1, 17, 40], jnp.int32)
+        lengths = jnp.array([1, 1, 0, 1], jnp.int32)
+        kv_width = None
+    else:
+        q_offset = jnp.array([0, 9, 50, 31], jnp.int32)
+        lengths = jnp.array([1, 1, 0, 1], jnp.int32)
+        kv_width = 32
+    cache, _ = model.init_cache(B, S)
+    kk, kv, kt = jax.random.split(jax.random.key(3), 3)
+    cache["k"] = jax.random.normal(kk, cache["k"].shape).astype(cfg.dtype)
+    cache["v"] = jax.random.normal(kv, cache["v"].shape).astype(cfg.dtype)
+    cache["seq_lens"] = q_offset
+    tokens = jax.random.randint(kt, (B, 1), 0, cfg.vocab)
+
+    kops.set_backend(backend)
+    try:
+        tick = jax.jit(lambda p, t, c, o, n: model.prefill_chunk(
+            p, t, c, q_offset=o, lengths=n, kv_width=kv_width))
+        got, logits = tick(params, tokens, dict(cache), q_offset, lengths)
+        k_ref, v_ref, logits_ref = jax.jit(
+            lambda p, t, c, o, n: _per_layer_decode_tick(model, p, t, c, o, n)
+        )(params, tokens, cache, q_offset, lengths)
+    finally:
+        kops.set_backend(None)
+    np.testing.assert_array_equal(np.asarray(got["k"]), np.asarray(k_ref))
+    np.testing.assert_array_equal(np.asarray(got["v"]), np.asarray(v_ref))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_array_equal(np.asarray(logits)[live],
+                                  np.asarray(logits_ref)[live])
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got[name])[:, ~live],
+                                      np.asarray(cache[name])[:, ~live])
+    assert np.all(np.asarray(got["seq_lens"]) ==
+                  np.where(live, np.asarray(q_offset) + 1, np.asarray(q_offset)))
